@@ -48,6 +48,7 @@ import json
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.sched import (FleetScheduler, RecoveryConfig, SchedulerConfig,
                          fault_trace, get_trace, reference_fault_trace,
                          trace_names)
@@ -231,6 +232,7 @@ def main(argv=None) -> None:
                     help="CI smoke: reference trace + gates, no MTBF sweep")
     ap.add_argument("--out", default=None, help="also write JSON here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     report = {
         "trace": args.scenario,

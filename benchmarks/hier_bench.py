@@ -23,6 +23,7 @@ import json
 import sys
 
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.sched import (FleetScheduler, RemapConfig, SchedulerConfig,
                          get_trace)
 
@@ -90,6 +91,7 @@ def main(argv=None) -> None:
     ap.add_argument("--trace-out", default="TRACE_hier.json")
     ap.add_argument("--out", default=None, help="also write JSON here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ratios = [4.0] if args.quick else args.ratios
     n_arrivals = 12 if args.quick else args.arrivals

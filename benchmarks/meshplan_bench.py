@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import SHAPES, get_config
 from repro.core.meshplan import (JobSpec, compare_strategies, fleet_nic_load,
                                  place_jobs, tpu_topology)
@@ -68,6 +69,7 @@ def scenario_b(out=print, sim_scale: float = 1.0):
 
 
 def main():
+    enable_compile_cache()
     scenario_a()
     scenario_b()
 

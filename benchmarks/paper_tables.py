@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import ClusterTopology, STRATEGIES, simulate
 from repro.core.workloads import REAL, SYNTHETIC
 
@@ -55,6 +56,7 @@ def run(metric: str = "wait_ms", real: bool = False,
 
 
 def main():
+    enable_compile_cache()
     run("wait_ms", real=False)
     run("finish_s", real=False)
     run("job_finish_s", real=False)

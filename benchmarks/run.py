@@ -6,8 +6,11 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     sections = []
 
     def section(title):

@@ -46,6 +46,7 @@ import sys
 import time
 
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.obs import export as obs_export
 from repro.sched import (AdmissionConfig, CellConfig, FleetScheduler,
                          RemapConfig, SchedulerConfig, get_trace,
@@ -427,6 +428,7 @@ def main(argv=None) -> None:
                          "acceptance traces, hard assertions")
     ap.add_argument("--out", default=None, help="also write JSON here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     n_arrivals = 12 if args.quick else args.arrivals
     strategies = (("blocked", "cyclic", "new") if args.quick

@@ -37,6 +37,7 @@ import sys
 import time
 
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.core.graphs import ClusterTopology
 from repro.core.mapping import ONE_SHOT_STRATEGIES, STRATEGIES, make_search_strategy
 from repro.core.meshplan import tpu_topology
@@ -329,6 +330,7 @@ def main(argv=None) -> None:
     ap.add_argument("--trace-out", default="TRACE_search.json")
     ap.add_argument("--out", default=None, help="also write JSON here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     recorder = obs.Recorder() if args.trace else obs.from_env()
     _rec_ctx = (obs.recording(recorder) if recorder is not None

@@ -29,6 +29,7 @@ import time
 import numpy as np
 
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.core.simulator import resolve_backend, simulate, simulate_batch
 from repro.sched import (FleetScheduler, SchedulerConfig, get_trace,
                          trace_names)
@@ -199,21 +200,13 @@ def main(argv=None) -> None:
     ap.add_argument("--out", default=None, help="also write JSON here")
     args = ap.parse_args(argv)
 
-    from repro.core.simulator import _jax_importable
-    backends = list(args.backends)
-    if not _jax_importable():
-        dropped = [b for b in backends if b in ("jax", "pallas")]
-        if dropped:
-            print(f"jax not importable — skipping backends {dropped}",
-                  file=sys.stderr)
-            backends = [b for b in backends if b not in dropped]
-
+    enable_compile_cache()
     repeats = 3 if args.quick else args.repeats
     recorder = obs.Recorder() if args.trace else obs.from_env()
     ctx = (obs.recording(recorder) if recorder is not None
            else contextlib.nullcontext())
     with ctx:
-        report = run(args.scenario, backends, repeats, args.batch_k,
+        report = run(args.scenario, args.backends, repeats, args.batch_k,
                      args.sched_arrivals, args.skip_sched)
     if recorder is not None:
         with open(args.trace_out, "w") as f:

@@ -37,6 +37,7 @@ import json
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.sched import (AutoscaleConfig, FleetScheduler, RemapConfig,
                          SchedulerConfig, get_trace)
 
@@ -151,6 +152,7 @@ def main(argv=None) -> None:
                     help="CI smoke: half horizon, hard assertions")
     ap.add_argument("--out", default=None, help="also write JSON here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     horizon = 120.0 if args.quick else args.horizon
     report = run_report(seed=args.seed, horizon=horizon,

@@ -19,13 +19,17 @@ Three implementations of the scan:
 * ``segmented`` — numpy: segmented prefix sums plus a segmented running
   minimum computed densely per server row (``np.minimum.accumulate`` on a
   (servers, max-queue) grid; doubling-sweep fallback when the grid would
-  blow up). Exact — matches ``loop`` to ~1e-12 relative.
-* ``jax``       — ``jax.lax.associative_scan`` over the ``(u, v)`` elements,
-  jitted, padded to powers of two to bound recompiles; float64 when
-  ``jax.experimental.enable_x64`` is available. Batches over a leading axis
-  for ``simulate_batch``.
+  blow up). Matches ``loop`` to ~1e-12 relative on stable loads; its
+  prefix sums run across all servers, so under sustained overload a
+  lightly loaded server's small waits cancel against large sums (4e-7
+  relative on one job's wait in the full-count Table-4 snapshot, where
+  ``jax`` holds 3e-11).
+* ``jax``       — a blocked ``jax.lax.associative_scan`` over the ``(u, v)``
+  elements, jitted, padded to powers of two to bound recompiles; float64
+  under ``jax.enable_x64``. Batches over a leading axis for
+  ``simulate_batch``.
 * ``pallas``    — ``repro.kernels.lindley_scan``: the same elements through
-  a chunked Pallas TPU kernel (float32; ``interpret=True`` on CPU).
+  a blocked Pallas TPU kernel (float32; interpreted off a TPU).
 
 Routing gives every message a *stage-0* server (cache / memory / its first
 hierarchy hop — disjoint id spaces form the scan's per-level server axis)
@@ -574,7 +578,47 @@ def _uv_elements(arr_s, srv_s, starts):
     return u, np.zeros(arr_s.size)
 
 
+# Device rows are padded to a power of two, and to at least one block of
+# the blocked jax scan — which is also a whole number of the Pallas
+# kernel's (8, 128) tiles — so a churning live fleet hits a bounded set
+# of compiled shapes.
+_SCAN_BLOCK = 1024
 _JAX_SCAN = None
+
+
+def _combine(a, b):
+    """Compose max-plus maps: ``a`` (earlier) followed by ``b``."""
+    import jax.numpy as jnp
+    au, av = a
+    bu, bv = b
+    return au + bu, jnp.maximum(av + bu, bv)
+
+
+def _blocked_scan(u, v):
+    """Inclusive max-plus scan along the last axis of power-of-two rows.
+
+    One ``associative_scan`` over all n elements compiles super-linearly
+    slowly for TPU (f64, TPU v5e target: 2^18 in 8 s, 2^20 in 86 s). Here
+    each ``_SCAN_BLOCK``-wide block scans alone, the block totals scan
+    recursively, and each block's exclusive prefix composes in front —
+    the compile stays a few seconds at 2^23.
+    """
+    import jax
+    import jax.numpy as jnp
+    n = u.shape[-1]
+    if n <= _SCAN_BLOCK:
+        return jax.lax.associative_scan(_combine, (u, v), axis=-1)
+    lead = u.shape[:-1]
+    blocks = (*lead, n // _SCAN_BLOCK, _SCAN_BLOCK)
+    lu, lv = jax.lax.associative_scan(
+        _combine, (u.reshape(blocks), v.reshape(blocks)), axis=-1)
+    tu, tv = _blocked_scan(lu[..., -1], lv[..., -1])
+    # maps before each block: the inclusive totals shifted one block on
+    pu = jnp.concatenate([jnp.zeros_like(tu[..., :1]), tu[..., :-1]], -1)
+    pv = jnp.concatenate([jnp.full_like(tv[..., :1], -jnp.inf),
+                          tv[..., :-1]], -1)
+    big_u, big_v = _combine((pu[..., None], pv[..., None]), (lu, lv))
+    return big_u.reshape(u.shape), big_v.reshape(v.shape)
 
 
 def _jax_scan_fn():
@@ -585,53 +629,53 @@ def _jax_scan_fn():
 
         @jax.jit
         def scan(u, v):
-            def comb(a, b):
-                au, av = a
-                bu, bv = b
-                return au + bu, jnp.maximum(av + bu, bv)
-            big_u, big_v = jax.lax.associative_scan(comb, (u, v), axis=-1)
-            return jnp.maximum(big_u, big_v)
+            return jnp.maximum(*_blocked_scan(u, v))
 
         _JAX_SCAN = scan
     return _JAX_SCAN
 
 
 def _waits_jax(u: np.ndarray, v: np.ndarray) -> np.ndarray:
-    """Run the (possibly batched) max-plus scan on the JAX backend.
-
-    Rows are padded to the next power of two with identity elements
-    ``(0, -inf)`` so live fleets (whose message count changes every
-    admission) hit a bounded set of compiled shapes.
-    """
-    import jax.numpy as jnp
-    try:
-        from jax.experimental import enable_x64
-    except ImportError:                     # pragma: no cover - old jax
-        enable_x64 = None
-    n = u.shape[-1]
-    npad = 1 << max(0, int(n - 1).bit_length())
-    if npad > n:
-        widths = [(0, 0)] * (u.ndim - 1) + [(0, npad - n)]
-        u = np.pad(u, widths, constant_values=0.0)
-        v = np.pad(v, widths, constant_values=-np.inf)
-    scan = _jax_scan_fn()
-    if enable_x64 is not None:
-        with enable_x64():
-            w = scan(jnp.asarray(u), jnp.asarray(v))
-    else:                                   # pragma: no cover - old jax
-        w = scan(jnp.asarray(u), jnp.asarray(v))
-    return np.asarray(w)[..., :n]
-
-
-def _waits_pallas(u: np.ndarray, v: np.ndarray) -> np.ndarray:
+    """The (possibly batched) max-plus scan on the JAX backend, in f64."""
     import jax
-    from ..kernels.lindley_scan import lindley_scan
-    squeeze = u.ndim == 1
-    if squeeze:
-        u, v = u[None], v[None]
-    w = np.asarray(lindley_scan(u, v,
-                                interpret=jax.default_backend() != "tpu"))
-    return w[0] if squeeze else w
+    import jax.numpy as jnp
+    with jax.enable_x64(True):
+        w = _jax_scan_fn()(jnp.asarray(u), jnp.asarray(v))
+        return np.asarray(w)
+
+
+def pallas_interpret() -> bool:
+    """Whether the Pallas kernel runs interpreted: everywhere but a TPU."""
+    import jax
+    return jax.default_backend() != "tpu"
+
+
+def _device_waits(u: np.ndarray, v: np.ndarray, backend: str) -> np.ndarray:
+    """Waits of (batch, n) element rows on the ``jax`` or ``pallas``
+    backend, padded with identity elements ``(0, -inf)`` past the tail."""
+    n = u.shape[-1]
+    npad = max(_SCAN_BLOCK, 1 << int(n - 1).bit_length())
+    widths = [(0, 0)] * (u.ndim - 1) + [(0, npad - n)]
+    u = np.pad(u, widths, constant_values=0.0)
+    v = np.pad(v, widths, constant_values=-np.inf)
+    if backend == "jax":
+        w = _waits_jax(u, v)
+    else:
+        from ..kernels.lindley_scan import lindley_scan
+        w = lindley_scan(u, v, interpret=pallas_interpret())
+    return np.asarray(w[..., :n], dtype=np.float64)
+
+
+def _waits_rows(rows, backend: str) -> list[np.ndarray]:
+    """Ragged ``(u, v)`` rows — e.g. one per candidate placement's stage —
+    stacked on the batch axis in one device call; per-row waits back."""
+    u2 = np.zeros((len(rows), max(u.size for u, _ in rows)))
+    v2 = np.full_like(u2, -np.inf)
+    for i, (u, v) in enumerate(rows):
+        u2[i, :u.size] = u
+        v2[i, :v.size] = v
+    w2 = _device_waits(u2, v2, backend)
+    return [w2[i, :u.size] for i, (u, _) in enumerate(rows)]
 
 
 def _util_max(arr_s, srv_s, w_s, starts) -> float:
@@ -648,8 +692,7 @@ def _pass_waits(arr_s, srv_s, starts, backend: str) -> np.ndarray:
     if backend == "segmented":
         return _segmented_waits_numpy(arr_s, srv_s, starts)
     u, v = _uv_elements(arr_s, srv_s, starts)
-    w = _waits_jax(u, v) if backend == "jax" else _waits_pallas(u, v)
-    return np.asarray(w, dtype=np.float64)
+    return _device_waits(u[None], v[None], backend)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -745,14 +788,8 @@ def simulate_scan(jobs: Sequence[AppGraph], placement: Placement,
 
 
 # ---------------------------------------------------------------------------
-# Batched candidate evaluation (JAX backend)
+# Batched candidate evaluation (device backends)
 # ---------------------------------------------------------------------------
-def _waits_batch(u: np.ndarray, v: np.ndarray, backend: str) -> np.ndarray:
-    if backend == "pallas":
-        return _waits_pallas(u, v)
-    return _waits_jax(u, v)
-
-
 def simulate_scan_batch(jobs: Sequence[AppGraph],
                         placements: Sequence[Placement],
                         cluster: ClusterTopology | None = None,
@@ -789,7 +826,7 @@ def simulate_scan_batch(jobs: Sequence[AppGraph],
         rows.append({"service": service, "stages": stages,
                      "order": order, "starts": starts})
 
-    w1 = _waits_batch(u1, v1, backend)
+    w1 = _device_waits(u1, v1, backend)
     results_state = []
     for k, st in enumerate(rows):
         order, starts = st["order"], st["starts"]
@@ -826,20 +863,8 @@ def simulate_scan_batch(jobs: Sequence[AppGraph],
             ragged.append((u, v))
         if not ragged:
             continue
-        # stage rows are ragged (routing differs per placement) — pad
-        # with the max-plus identity onto one batched row axis
-        if backend == "pallas":
-            from ..kernels.lindley_scan import lindley_scan_rows
-            ws = lindley_scan_rows(ragged)
-        else:
-            max_l = max(u.size for u, _ in ragged)
-            u2 = np.zeros((len(ragged), max_l))
-            v2 = np.full((len(ragged), max_l), -np.inf)
-            for i, (u, v) in enumerate(ragged):
-                u2[i, :u.size] = u
-                v2[i, :v.size] = v
-            w2 = _waits_jax(u2, v2)
-            ws = [w2[i, :u.size] for i, (u, _) in enumerate(ragged)]
+        # stage rows are ragged (routing differs per placement)
+        ws = _waits_rows(ragged, backend)
         for k, p2 in enumerate(passes):
             if p2 is None:
                 continue

@@ -33,19 +33,18 @@ Backends (DESIGN.md §8):
   Kept as the bit-faithful reference and the benchmark baseline.
 * ``segmented`` — numpy segmented max-plus scan over ALL servers at once
   (``repro.core.sim_scan``); no per-server Python loop, flat-message cache.
-* ``jax``       — ``jax.lax.associative_scan`` over the same max-plus
-  elements; batches K candidate placements in one device call
+* ``jax``       — a float64 ``jax.lax.associative_scan`` over the same
+  max-plus elements; batches K candidate placements in one device call
   (``simulate_batch``).
-* ``pallas``    — the ``repro.kernels.lindley_scan`` chunked Pallas kernel
-  (float32; validated via ``interpret=True`` like ``ssd_scan``).
-* ``auto``      — ``segmented`` on CPU-only hosts, ``jax`` when an
-  accelerator is attached. ``REPRO_SIM_BACKEND`` overrides.
+* ``pallas``    — the ``repro.kernels.lindley_scan`` Pallas TPU kernel
+  (float32; interpreted off a TPU, as the CPU tests run it).
+* ``auto``      — ``segmented`` when JAX's platform is the CPU, ``jax``
+  on an accelerator. ``REPRO_SIM_BACKEND`` overrides.
 """
 from __future__ import annotations
 
 import dataclasses
 import os
-import sys
 import time
 from typing import Sequence
 
@@ -87,54 +86,22 @@ class SimResult:
         return self.total_wait * 1e3
 
 
-def _jax_importable() -> bool:
-    try:
-        import jax  # noqa: F401
-        return True
-    except Exception:  # pragma: no cover - env without jax
-        return False
-
-
-def _accelerator_attached() -> bool:
-    try:
-        import jax
-        return any(d.platform != "cpu" for d in jax.devices())
-    except Exception:  # pragma: no cover - env without jax
-        return False
-
-
-_AUTO_BACKEND: str | None = None
-
-
 def resolve_backend(backend: str | None = None) -> str:
-    """Resolve a backend name (``auto``/None/env override -> concrete)."""
-    global _AUTO_BACKEND
+    """Resolve a backend name (``auto``/None/env override -> concrete).
+
+    ``auto`` asks JAX for its platform: numpy ``segmented`` wins on a CPU
+    (no dispatch or compile overhead), the device scan everywhere else.
+    """
     backend = backend or "auto"
     if backend == "auto":
         env = os.environ.get("REPRO_SIM_BACKEND", "").strip()
         if env and env != "auto":
             backend = env
-        elif _AUTO_BACKEND is not None:
-            backend = _AUTO_BACKEND
-        elif "jax" not in sys.modules:
-            # nothing has imported jax yet -> no accelerator runtime is in
-            # play; answer "segmented" WITHOUT initializing jax (and don't
-            # memoize — jax may be imported later in the process)
-            backend = "segmented"
         else:
-            # numpy segmented wins on CPU (no dispatch/compile overhead);
-            # the JAX scan pays off on a real accelerator
-            _AUTO_BACKEND = ("jax" if _accelerator_attached()
-                             else "segmented")
-            backend = _AUTO_BACKEND
+            import jax
+            backend = "segmented" if jax.default_backend() == "cpu" else "jax"
     if backend not in BACKENDS:
         raise KeyError(f"unknown sim backend {backend!r}; known: {BACKENDS}")
-    if backend in ("jax", "pallas") and not _jax_importable():
-        # only explicit (arg/env) requests can reach here — "auto" never
-        # picks jax without jax importable. Fail loudly rather than
-        # silently run segmented while claiming jax numbers.
-        raise ImportError(f"sim backend {backend!r} requires jax; "
-                          f"install jax or use backend='auto'")
     return backend
 
 

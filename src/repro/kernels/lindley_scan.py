@@ -1,19 +1,26 @@
 """Pallas TPU kernel for the segmented max-plus Lindley scan.
 
-TARGET: TPU. Grid = (batch, n_chunks); the chunk axis is minor-most
-(sequential), so the 2-scalar carry — the composed max-plus map of every
-message seen so far — lives in VMEM scratch and never round-trips HBM
-between chunks, the same shape as ``ssd_scan``'s inter-chunk state.
+TARGET: TPU. Each row of ``n`` elements is laid out as ``(n / 128, 128)``
+tiles, row-major, so one block is ``(BLOCK_ROWS, 128)`` consecutive
+elements — a whole number of ``(8, 128)`` float32 vreg tiles. Grid =
+``(batch, n_blocks)``; the block axis is minor-most (sequential), so the
+carry — the composed max-plus map of every element seen so far — lives
+in VMEM scratch as a lane-broadcast ``(1, 128)`` pair and never
+round-trips HBM between blocks.
 
 Elements are the affine max-plus maps of ``repro.core.sim_scan``:
 ``(u, v): w -> max(w + u, v)`` — a message contributes ``(X_n, 0)``, a
 server's first message (segment head) contributes ``(-inf, 0)``, padding
-contributes the identity ``(0, -inf)``. Within a chunk the scan runs as an
-associative scan on the VPU; across chunks the carry composes sequentially.
-Waits are ``W = max(U, V)`` of the inclusive prefix maps.
+contributes the identity ``(0, -inf)``. Within a block the inclusive scan
+runs in three Hillis-Steele sweeps built from ``pltpu.roll`` and iota
+masks (lowered by Mosaic to lane/sublane rotates and selects): along
+the 128 lanes of every tile row, then down the rows over each row's
+lane-broadcast total, then the carry composes in front. Waits are
+``W = max(U, V)`` of the inclusive prefix maps.
 
-Validated on CPU via ``interpret=True`` against the numpy segmented
-backend (float32 — tolerances are looser than the f64 backends).
+float32 (DESIGN.md §8). Validated on CPU via ``interpret=True`` against
+the numpy segmented backend; ``interpret`` has no default — the caller
+decides from the platform.
 """
 from __future__ import annotations
 
@@ -24,89 +31,90 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
+SUBLANES = 8
+BLOCK_ROWS = 512          # tile rows per block: 64K elements, 256 KiB f32
+
 
 def _combine(a, b):
+    """Compose max-plus maps: ``a`` (earlier) followed by ``b``."""
     au, av = a
     bu, bv = b
     return au + bu, jnp.maximum(av + bu, bv)
 
 
-def _lindley_kernel(u_ref, v_ref, w_ref, carry_ref):
-    ci = pl.program_id(1)
-
-    @pl.when(ci == 0)
-    def _init():
-        carry_ref[0, 0] = 0.0           # identity map: w -> max(w + 0, -inf)
-        carry_ref[0, 1] = -jnp.inf
-
-    u = u_ref[0]                         # (chunk,)
-    v = v_ref[0]
-    loc_u, loc_v = jax.lax.associative_scan(_combine, (u, v))
-    cu = carry_ref[0, 0]
-    cv = carry_ref[0, 1]
-    tot_u = cu + loc_u                   # carry . local, elementwise prefix
-    tot_v = jnp.maximum(cv + loc_u, loc_v)
-    w_ref[0] = jnp.maximum(tot_u, tot_v)  # W_n with W_0 = 0
-    carry_ref[0, 0] = tot_u[-1]
-    carry_ref[0, 1] = tot_v[-1]
+def _shift(u, v, d: int, axis: int, pos):
+    """Maps ``d`` places earlier along ``axis``; the identity before the
+    start of the tile."""
+    keep = pos >= d
+    return (jnp.where(keep, pltpu.roll(u, d, axis), 0.0),
+            jnp.where(keep, pltpu.roll(v, d, axis), -jnp.inf))
 
 
-def lindley_scan_rows(rows, *, chunk: int = 512,
-                      interpret: bool = True) -> list:
-    """Ragged batch: one kernel launch for rows of different lengths.
-
-    ``rows`` is a list of ``(u, v)`` 1-D element pairs — e.g. one row per
-    hierarchy level/stage or per candidate placement (DESIGN.md §9). Rows
-    are padded to a common length with the max-plus identity ``(0, -inf)``
-    (padding cannot change any real prefix) and stacked on the kernel's
-    row axis; returns the unpadded per-row waits.
-    """
-    import numpy as np
-    if not rows:
-        return []
-    n = max(u.shape[0] for u, _ in rows)
-    # pad the scan axis to a power of two (at least one chunk) so a churning
-    # live fleet — whose message count changes on every scheduler event —
-    # hits a bounded set of compiled shapes (mirrors sim_scan._waits_jax)
-    n = max(chunk, 1 << max(0, int(n - 1).bit_length()))
-    ub = np.zeros((len(rows), n), np.float32)
-    vb = np.full((len(rows), n), -np.inf, np.float32)
-    for i, (u, v) in enumerate(rows):
-        ub[i, :u.shape[0]] = u
-        vb[i, :v.shape[0]] = v
-    w = np.asarray(lindley_scan(ub, vb, chunk=chunk, interpret=interpret))
-    return [w[i, :u.shape[0]] for i, (u, _) in enumerate(rows)]
+def _scan(u, v, axis: int):
+    """Inclusive scan of a tile along ``axis`` (log2(extent) sweeps)."""
+    pos = jax.lax.broadcasted_iota(jnp.int32, u.shape, axis)
+    d = 1
+    while d < u.shape[axis]:
+        u, v = _combine(_shift(u, v, d, axis, pos), (u, v))
+        d *= 2
+    return u, v
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def lindley_scan(u: jax.Array, v: jax.Array, *, chunk: int = 512,
-                 interpret: bool = True) -> jax.Array:
+def _lindley_kernel(u_ref, v_ref, w_ref, cu_ref, cv_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():                             # identity map
+        cu_ref[...] = jnp.zeros(cu_ref.shape, jnp.float32)
+        cv_ref[...] = jnp.full(cv_ref.shape, -jnp.inf, jnp.float32)
+
+    lu, lv = _scan(u_ref[...], v_ref[...], axis=1)    # within tile rows
+    shape = lu.shape
+    # each row's total (lane 127), broadcast over lanes, scanned down rows
+    ru, rv = _scan(jnp.broadcast_to(lu[:, LANES - 1:], shape),
+                   jnp.broadcast_to(lv[:, LANES - 1:], shape), axis=0)
+    # maps before each row = carry . (rows above), then . (row prefix)
+    eu, ev = _shift(ru, rv, 1, 0, jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0))
+    cu, cv = cu_ref[...], cv_ref[...]
+    pu, pv = _combine((jnp.broadcast_to(cu, shape),
+                       jnp.broadcast_to(cv, shape)), (eu, ev))
+    big_u, big_v = _combine((pu, pv), (lu, lv))
+    w_ref[...] = jnp.maximum(big_u, big_v)            # W_n with W_0 = 0
+    cu_ref[...], cv_ref[...] = _combine((cu, cv), (ru[-1:], rv[-1:]))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def lindley_scan(u: jax.Array, v: jax.Array, *, interpret: bool) -> jax.Array:
     """Batched waits for max-plus element rows.
 
     u, v: (batch, n) map coefficients in sorted (server, arrival) order.
     The batch axis carries whatever the caller stacks — K candidate
-    placements, independent hierarchy stages, or both (see
-    ``lindley_scan_rows`` for the ragged form).
-    Returns W: (batch, n) float32 waiting times.
+    placements, independent hierarchy stages, or both. Rows are padded
+    with the identity ``(0, -inf)`` to whole blocks of up to
+    ``BLOCK_ROWS`` tile rows. Returns W: (batch, n) float32 waiting
+    times.
     """
     u = jnp.asarray(u, jnp.float32)
     v = jnp.asarray(v, jnp.float32)
     b, n = u.shape
-    nc = pl.cdiv(n, chunk)
-    npad = nc * chunk
+    rows = SUBLANES * pl.cdiv(n, SUBLANES * LANES)
+    br = min(BLOCK_ROWS, rows)
+    rows = br * pl.cdiv(rows, br)
+    npad = rows * LANES
     if npad > n:
         u = jnp.pad(u, ((0, 0), (0, npad - n)))
         v = jnp.pad(v, ((0, 0), (0, npad - n)), constant_values=-jnp.inf)
+    spec = pl.BlockSpec((None, br, LANES), lambda bi, ci: (bi, ci, 0))
     w = pl.pallas_call(
         _lindley_kernel,
-        grid=(b, nc),
-        in_specs=[
-            pl.BlockSpec((1, chunk), lambda bi, ci: (bi, ci)),
-            pl.BlockSpec((1, chunk), lambda bi, ci: (bi, ci)),
-        ],
-        out_specs=pl.BlockSpec((1, chunk), lambda bi, ci: (bi, ci)),
-        out_shape=jax.ShapeDtypeStruct((b, npad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((1, 2), jnp.float32)],
+        grid=(b, rows // br),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((b, rows, LANES), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, LANES), jnp.float32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(u, v)
-    return w[:, :n]
+        name="lindley_scan",
+    )(u.reshape(b, rows, LANES), v.reshape(b, rows, LANES))
+    return w.reshape(b, npad)[:, :n]

@@ -23,7 +23,6 @@ Distribution: two code paths with IDENTICAL numerics —
 """
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -31,11 +30,6 @@ from jax.sharding import PartitionSpec as P
 from ..configs import ModelConfig
 from ..parallel import active_plan
 from .layers import dense_init, mlp_forward, mlp_init
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 
 def moe_init(key, cfg: ModelConfig, dtype) -> dict:
@@ -211,7 +205,7 @@ def _moe_shard_map(routed, router, x, cfg: ModelConfig, plan):
             y = jax.lax.psum(y.astype(x_l.dtype), model_axis)
         return y
 
-    fn = shard_map(local, mesh=plan.mesh,
-                   in_specs=(w_specs, P(None, None), x_spec),
-                   out_specs=x_spec, check_vma=False)
+    fn = jax.shard_map(local, mesh=plan.mesh,
+                       in_specs=(w_specs, P(None, None), x_spec),
+                       out_specs=x_spec, check_vma=False)
     return fn(routed, router, x)

@@ -13,8 +13,6 @@ import sys
 
 import pytest
 
-from jax_compat import cost_analysis_is_dict, shard_map_supports_vma
-
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 DRYRUN = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun")
 
@@ -31,9 +29,6 @@ def _run(code: str, devices: int = 8) -> str:
 
 def test_parsed_allreduce_matches_ring_formula():
     """One explicit psum: parsed wire bytes == 2(k-1)/k * payload."""
-    if not shard_map_supports_vma():
-        pytest.skip("installed jax lacks jax.shard_map(..., check_vma=) "
-                    "(needs jax >= 0.6); env-dependent, not a code defect")
     _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
@@ -57,10 +52,6 @@ print('psum wire bytes OK')
 def test_planned_mesh_compiles():
     """make_planned_mesh: the paper-mapped device order builds a valid
     Mesh and a step compiles on it (the device permutation is sound)."""
-    if not cost_analysis_is_dict():
-        pytest.skip("installed jax returns a list from "
-                    "Compiled.cost_analysis() (dict API needs newer jax); "
-                    "env-dependent, not a code defect")
     _run("""
 import jax
 from repro.configs import get_smoke_config, ShapeSpec

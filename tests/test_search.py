@@ -31,15 +31,7 @@ from repro.search import (SearchState, domain_sizes, neighbours,
                           objective_of, search_placement, search_strategy,
                           search_strategy_result)
 
-def _jax_importable() -> bool:
-    try:
-        import jax  # noqa: F401
-        return True
-    except Exception:
-        return False
-
-
-BACKENDS = ["loop", "segmented"] + (["jax"] if _jax_importable() else [])
+BACKENDS = ["loop", "segmented", "jax"]
 
 
 def small_cluster() -> ClusterTopology:

@@ -346,22 +346,67 @@ def test_simulate_batch_pallas_smoke():
                       "batch[pallas] total_wait")
 
 
-def test_lindley_scan_rows_ragged():
+@pytest.mark.parametrize("backend", ["pallas", "jax"])
+def test_lindley_scan_rows_ragged(backend):
     """Ragged level/stage rows pad with the max-plus identity and match a
-    scalar Lindley reference per row."""
-    from repro.kernels.lindley_scan import lindley_scan_rows
+    scalar f64 Lindley reference per row; the 3000-element row spans the
+    kernel's 128-lane tile rows and runs down the rows of a block, with
+    segment heads inside it. f32 ``pallas`` holds 1e-5 absolute on
+    these O(10) waits; f64 ``jax`` holds 1e-12 relative."""
+    from repro.core.sim_scan import _waits_rows
     rng = np.random.default_rng(0)
     rows = []
     for n in (5, 17, 3, 64):
         u = rng.uniform(-1, 1, n).astype(np.float32)
         u[0] = -np.inf                    # segment head: W_0 = 0
         rows.append((u, np.zeros(n, np.float32)))
-    for (u, v), w in zip(rows, lindley_scan_rows(rows)):
+    rng = np.random.default_rng(1)
+    u = rng.uniform(-1, 1, 3000).astype(np.float32)
+    u[0] = -np.inf
+    u[rng.random(3000) < 0.01] = -np.inf
+    rows.append((u, np.zeros(3000, np.float32)))
+    tol = {"pallas": dict(atol=1e-5), "jax": dict(rtol=1e-12, atol=1e-12)}
+    for (u, v), w in zip(rows, _waits_rows(rows, backend)):
         cur, ref = 0.0, []
         for i in range(len(u)):
-            cur = max(cur + u[i], v[i]) if i else 0.0
+            cur = max(cur + float(u[i]), float(v[i])) if i else 0.0
             ref.append(cur)
-        np.testing.assert_allclose(w, ref, atol=1e-5)
+        np.testing.assert_allclose(w, ref, **tol[backend])
+
+
+def test_lindley_scan_carry_across_blocks():
+    """Rows longer than one (BLOCK_ROWS, 128) block: the VMEM carry
+    composes across grid steps, and the ragged tail pads to a block."""
+    from repro.core.sim_scan import _device_waits
+    from repro.kernels.lindley_scan import BLOCK_ROWS, LANES, lindley_scan
+    rng = np.random.default_rng(3)
+    u = rng.uniform(-1, 1, (2, 2 * BLOCK_ROWS * LANES + 5000))
+    u[:, 0] = -np.inf                     # one queue per row, one head
+    v = np.zeros_like(u)
+    got = np.asarray(lindley_scan(u, v, interpret=True))
+    np.testing.assert_allclose(got, _device_waits(u, v, "jax"),
+                               rtol=1e-4, atol=1e-3)
+
+
+def test_jax_backend_is_f64():
+    """The ``jax`` scan runs in float64 (the 1e-9 contract's abs tolerance
+    would hide an f32 scan on ~1e-3 s waits): absolute arrival times near
+    1e4 s with microsecond services, near saturation, agree with numpy
+    ``segmented`` to 1e-12 relative — f32 misses by ~1e-7."""
+    from repro.core.sim_scan import (_pass_waits, _segment_starts,
+                                     _uv_elements, _waits_jax)
+    rng = np.random.default_rng(5)
+    sid = np.repeat(np.arange(8), 512)            # 4096: no padding
+    arr = np.concatenate([1e4 + k + np.cumsum(rng.exponential(1e-6, 512))
+                          for k in range(8)])
+    srv = rng.uniform(0.5e-6, 1.4e-6, arr.size)
+    starts = _segment_starts(sid)
+    u, v = _uv_elements(arr, srv, starts)
+    assert _waits_jax(u, v).dtype == np.float64
+    want = _pass_waits(arr, srv, starts, "segmented")
+    got = _pass_waits(arr, srv, starts, "jax")
+    assert want.max() > 1e-6                       # queues really build
+    np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-18)
 
 
 def test_order_by_server_arrival_repairs_ties_to_original_order():
@@ -421,9 +466,12 @@ def test_scan_r2_tie_repair_cross_job_collision():
     assert base.total_wait > 0.0          # ties queued at the shared RX
 
 
-def test_resolve_backend():
+def test_resolve_backend(monkeypatch):
+    import jax
     assert resolve_backend("loop") == "loop"
-    assert resolve_backend("auto") in BACKENDS
+    monkeypatch.delenv("REPRO_SIM_BACKEND", raising=False)
+    assert resolve_backend("auto") == (
+        "segmented" if jax.default_backend() == "cpu" else "jax")
     assert resolve_backend(None) in BACKENDS
     with pytest.raises(KeyError):
         resolve_backend("omnetpp")

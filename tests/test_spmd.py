@@ -5,10 +5,6 @@ import os
 import subprocess
 import sys
 
-import pytest
-
-from jax_compat import cost_analysis_is_dict, shard_map_supports_vma
-
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
@@ -24,13 +20,9 @@ def _run(code: str, devices: int = 8) -> str:
 
 def test_moe_shard_map_matches_pure_path():
     """Manual-EP shard_map MoE == single-device pure path, bit-for-bit-ish."""
-    if not shard_map_supports_vma():
-        pytest.skip("installed jax lacks shard_map(..., check_vma=) used by "
-                    "the manual-EP path (needs jax >= 0.6); env-dependent, "
-                    "not a code defect")
     _run("""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 from repro.configs import get_smoke_config, SHAPES
 from repro.models.moe import moe_init, moe_forward
 from repro.parallel import make_plan, activate
@@ -41,7 +33,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
 
 y_pure, aux_pure = moe_forward(p, x, cfg)        # no active plan
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))  # 4 experts over 4 shards
+mesh = jax.make_mesh((2, 4), ('data', 'model'), axis_types=(AxisType.Auto,) * 2)  # 4 experts over 4 shards
 plan = make_plan(mesh, cfg, SHAPES['train_4k'])
 assert plan.rules['experts'] == 'model'
 plan.rules['seq'] = None    # psum path: exact group-dispatch equality
@@ -67,7 +59,7 @@ cfg2 = get_smoke_config('qwen2-moe-a2.7b')       # 6 experts, ff sharded
 p2 = moe_init(jax.random.PRNGKey(2), cfg2, jnp.float32)
 x2 = jax.random.normal(jax.random.PRNGKey(3), (4, 16, cfg2.d_model))
 y2_pure, _ = moe_forward(p2, x2, cfg2)
-mesh2 = jax.make_mesh((2, 4), ('data', 'model'))  # 6 % 4 != 0 -> ff path
+mesh2 = jax.make_mesh((2, 4), ('data', 'model'), axis_types=(AxisType.Auto,) * 2)  # 6 % 4 != 0 -> ff path
 plan2 = make_plan(mesh2, cfg2, SHAPES['train_4k'])
 assert plan2.rules['experts'] is None and plan2.rules['ff'] == 'model'
 with mesh2, activate(plan2):
@@ -81,6 +73,7 @@ def test_sharded_train_step_matches_single_device():
     """One jitted train step on a 2x2 mesh == the unsharded step."""
     _run("""
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.configs import get_smoke_config, SHAPES
 from repro.models import build_model
 from repro.train import AdamW, TrainPlan, make_train_step
@@ -98,7 +91,7 @@ batch = data(0)
 step = make_train_step(model, opt, TrainPlan())
 p_ref, s_ref, m_ref = jax.jit(step)(params, state, batch)
 
-mesh = jax.make_mesh((2, 2), ('data', 'model'))
+mesh = jax.make_mesh((2, 2), ('data', 'model'), axis_types=(AxisType.Auto,) * 2)
 plan = make_plan(mesh, cfg, SHAPES['train_4k'])
 ps = param_specs(plan, params)
 os_ = opt_state_specs(plan, params, state)
@@ -118,16 +111,14 @@ print('SPMD train step OK')
 def test_mini_dryrun_lowers_and_compiles():
     """A miniature production mesh (2x2x2 pod/data/model) lowers+compiles
     train, prefill and decode for a smoke arch — the multi-pod pattern."""
-    if not cost_analysis_is_dict():
-        pytest.skip("installed jax returns a list from "
-                    "Compiled.cost_analysis() (dict API needs newer jax); "
-                    "env-dependent, not a code defect")
     _run("""
 import jax, numpy as np
+from jax.sharding import AxisType
 from repro.configs import get_smoke_config, SHAPES, ShapeSpec
 from repro.launch.specs import build_step, lower_step
 
-mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'),
+                     axis_types=(AxisType.Auto,) * 3)
 cfg = get_smoke_config('granite-3-2b')
 for name, kind, seq, gb in [('train', 'train', 64, 8),
                             ('prefill', 'prefill', 64, 4),
@@ -145,6 +136,7 @@ def test_elastic_restart_reshards_checkpoint():
     """Save on a 2x2 mesh, lose half the fleet, restore onto 1x2 mesh."""
     _run("""
 import jax, numpy as np, tempfile, os
+from jax.sharding import AxisType
 from repro.configs import get_smoke_config, SHAPES
 from repro.models import build_model
 from repro.parallel import make_plan, param_specs
@@ -154,7 +146,7 @@ cfg = get_smoke_config('qwen3-0.6b')
 model = build_model(cfg)
 params = model.init(jax.random.PRNGKey(0))
 
-mesh = jax.make_mesh((2, 2), ('data', 'model'))
+mesh = jax.make_mesh((2, 2), ('data', 'model'), axis_types=(AxisType.Auto,) * 2)
 plan = make_plan(mesh, cfg, SHAPES['train_4k'])
 sharded = jax.device_put(params, param_specs(plan, params))
 
