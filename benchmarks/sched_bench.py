@@ -28,8 +28,8 @@ gate at <= 3%.
 verdict lands in ``--trace-out`` (native ``repro-trace-v1`` JSON, plus
 a ``.perfetto.json`` sibling loadable at https://ui.perfetto.dev), with
 each strategy leg on its own Perfetto process. Dumps are byte-identical
-across repeated seeded runs; ``--trace-wall`` opts into the
-wall-clock profiling fields at the cost of that determinism.
+across repeated seeded runs; host time is ``repro.obs.host_summary``'s
+(or a JAX profiler trace's), never the dump's.
 
     PYTHONPATH=src python benchmarks/sched_bench.py \
         --scenario rack_oversub --trace --trace-out TRACE_sched.json
@@ -393,9 +393,6 @@ def main(argv=None) -> None:
     ap.add_argument("--trace-out", default="TRACE_sched.json",
                     help="native trace output path (a .perfetto.json "
                          "sibling is written next to it)")
-    ap.add_argument("--trace-wall", action="store_true",
-                    help="include wall-clock profiling fields in the trace "
-                         "(forfeits byte-determinism)")
     ap.add_argument("--strategies", nargs="+", default=list(DEFAULT_STRATEGIES))
     ap.add_argument("--rate", type=float, default=None,
                     help="Poisson arrival rate, jobs/s (trace default if unset)")
@@ -494,14 +491,12 @@ def main(argv=None) -> None:
     if recorder is not None:
         doc = recorder.dump(
             extra_metrics={f"sched/{s}": row["metrics"]
-                           for s, row in report["strategies"].items()},
-            include_wall=args.trace_wall)
+                           for s, row in report["strategies"].items()})
         with open(args.trace_out, "w") as f:
             json.dump(doc, f, indent=1, sort_keys=True)
         perfetto_out = args.trace_out.replace(".json", "") + ".perfetto.json"
         with open(perfetto_out, "w") as f:
-            json.dump(obs_export.to_chrome(doc, include_wall=args.trace_wall),
-                      f, indent=1, sort_keys=True)
+            json.dump(obs_export.to_chrome(doc), f, indent=1, sort_keys=True)
         print(f"trace: {recorder.n_events()} events -> {args.trace_out} "
               f"(+ {perfetto_out})", file=sys.stderr)
     _print_table(report)

@@ -303,22 +303,24 @@ def flatten_delta(jobs: Sequence[AppGraph], count_scale: float,
     online scheduler's per-event churn (DESIGN.md §3).
     """
     jobs = list(jobs)
-    if prev is not None and count_scale == prev.count_scale:
+    if prev is None or count_scale != prev.count_scale:
+        return _flatten(jobs, count_scale)
+    with obs.span("sim.flatten"):
         if [id(j) for j in jobs] == [id(j) for j in prev.jobs]:
             _count("sim.flatten.reuse")
             return prev
         steps = _delta_steps(prev, jobs)
-        if steps is not None:
-            removed, added = steps
-            flat = prev
-            for jid in removed:
-                flat = flat.with_job_removed(jid)
-            for job in added:
-                flat = flat.with_job_added(job)
-            _cache_put(flat)
-            _count("sim.flatten.delta")
-            return flat
-    return _flatten(jobs, count_scale)
+        if steps is None:
+            return _flatten_cached(jobs, count_scale)
+        removed, added = steps
+        flat = prev
+        for jid in removed:
+            flat = flat.with_job_removed(jid)
+        for job in added:
+            flat = flat.with_job_added(job)
+        _cache_put(flat)
+        _count("sim.flatten.delta")
+        return flat
 
 
 def _cache_put(flat: _WorkloadFlat) -> None:
@@ -330,6 +332,12 @@ def _cache_put(flat: _WorkloadFlat) -> None:
 
 
 def _flatten(jobs: Sequence[AppGraph], count_scale: float) -> _WorkloadFlat:
+    with obs.span("sim.flatten"):
+        return _flatten_cached(jobs, count_scale)
+
+
+def _flatten_cached(jobs: Sequence[AppGraph],
+                    count_scale: float) -> _WorkloadFlat:
     key = (tuple(id(j) for j in jobs), count_scale)
     flat = _FLAT_CACHE.get(key)
     if flat is None:
@@ -650,32 +658,46 @@ def pallas_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _device_waits(u: np.ndarray, v: np.ndarray, backend: str) -> np.ndarray:
-    """Waits of (batch, n) element rows on the ``jax`` or ``pallas``
-    backend, padded with identity elements ``(0, -inf)`` past the tail."""
+def _pad(u: np.ndarray, v: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """(batch, n) element rows padded with identity elements ``(0, -inf)``
+    past the tail to a power of two, at least one scan block."""
     n = u.shape[-1]
     npad = max(_SCAN_BLOCK, 1 << int(n - 1).bit_length())
     widths = [(0, 0)] * (u.ndim - 1) + [(0, npad - n)]
-    u = np.pad(u, widths, constant_values=0.0)
-    v = np.pad(v, widths, constant_values=-np.inf)
-    if backend == "jax":
-        w = _waits_jax(u, v)
-    else:
-        from ..kernels.lindley_scan import lindley_scan
-        w = lindley_scan(u, v, interpret=pallas_interpret())
-    return np.asarray(w[..., :n], dtype=np.float64)
+    return (np.pad(u, widths, constant_values=0.0),
+            np.pad(v, widths, constant_values=-np.inf))
 
 
-def _waits_rows(rows, backend: str) -> list[np.ndarray]:
+def _device_scan(u: np.ndarray, v: np.ndarray, n: int,
+                 backend: str) -> np.ndarray:
+    """Waits of padded rows on the ``jax`` or ``pallas`` backend, the
+    first ``n`` of each, back on the host in float64. The ``sim.device``
+    span counts the bytes sent (the ``pallas`` kernel takes float32)."""
+    with obs.span("sim.device") as s:
+        s.count((u.size + v.size) * (8 if backend == "jax" else 4))
+        if backend == "jax":
+            w = _waits_jax(u, v)
+        else:
+            from ..kernels.lindley_scan import lindley_scan
+            w = lindley_scan(u, v, interpret=pallas_interpret())
+        return np.asarray(w[..., :n], dtype=np.float64)
+
+
+def _device_waits(u: np.ndarray, v: np.ndarray, backend: str) -> np.ndarray:
+    """Waits of (batch, n) element rows on the ``jax`` or ``pallas``
+    backend, padded with identity elements ``(0, -inf)`` past the tail."""
+    return _device_scan(*_pad(u, v), u.shape[-1], backend)
+
+
+def _stack_rows(rows) -> tuple[np.ndarray, np.ndarray]:
     """Ragged ``(u, v)`` rows — e.g. one per candidate placement's stage —
-    stacked on the batch axis in one device call; per-row waits back."""
+    stacked on the batch axis, identity elements past each row's tail."""
     u2 = np.zeros((len(rows), max(u.size for u, _ in rows)))
     v2 = np.full_like(u2, -np.inf)
     for i, (u, v) in enumerate(rows):
         u2[i, :u.size] = u
         v2[i, :v.size] = v
-    w2 = _device_waits(u2, v2, backend)
-    return [w2[i, :u.size] for i, (u, _) in enumerate(rows)]
+    return u2, v2
 
 
 def _util_max(arr_s, srv_s, w_s, starts) -> float:
@@ -690,9 +712,12 @@ def _util_max(arr_s, srv_s, w_s, starts) -> float:
 def _pass_waits(arr_s, srv_s, starts, backend: str) -> np.ndarray:
     """Sorted-domain waits for one multi-server round on any backend."""
     if backend == "segmented":
-        return _segmented_waits_numpy(arr_s, srv_s, starts)
-    u, v = _uv_elements(arr_s, srv_s, starts)
-    return _device_waits(u[None], v[None], backend)[0]
+        with obs.span("sim.scan"):
+            return _segmented_waits_numpy(arr_s, srv_s, starts)
+    with obs.span("sim.pack"):
+        u, v = _uv_elements(arr_s, srv_s, starts)
+        u, v = _pad(u[None], v[None])
+    return _device_scan(u, v, arr_s.size, backend)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -744,47 +769,54 @@ def simulate_scan(jobs: Sequence[AppGraph], placement: Placement,
         flat = _flatten(jobs, count_scale)
     if flat.n_messages == 0:
         return _empty_result(jobs)
-    sid1_p, service_p, stages = _route_pairs(cluster, flat, placement)
+    with obs.span("sim.route"):
+        sid1_p, service_p, stages = _route_pairs(cluster, flat, placement)
 
     # ---- stage 0: every message at its first server ----------------------
-    order, po_s, starts, r = _round1_order(flat, sid1_p)
-    arr_s = flat.emit_t[r]
-    srv_s = service_p[po_s]
+    with obs.span("sim.order"):
+        order, po_s, starts, r = _round1_order(flat, sid1_p)
+        arr_s = flat.emit_t[r]
+        srv_s = service_p[po_s]
     w_s = _pass_waits(arr_s, srv_s, starts, backend)
-    util = _util_max(arr_s, srv_s, w_s, starts)
-    deliver_s = arr_s + w_s + srv_s
-    n = flat.n_messages
-    wait = np.empty(n)
-    wait[order] = w_s
-    deliver = np.empty(n)
-    deliver[order] = deliver_s
+    with obs.span("sim.reduce"):
+        util = _util_max(arr_s, srv_s, w_s, starts)
+        deliver_s = arr_s + w_s + srv_s
+        n = flat.n_messages
+        wait = np.empty(n)
+        wait[order] = w_s
+        deliver = np.empty(n)
+        deliver[order] = deliver_s
 
     # ---- later stages: hierarchy hops fed by previous departures ---------
     for stage in stages:
-        rows = np.flatnonzero(stage.mask[flat.pair_of])
-        po = flat.pair_of[rows]
-        arrive = deliver[rows] + stage.latency[po]
-        srv2 = stage.service[po]
-        sid2 = stage.sid[po]
-        # FIFO departures are monotone per previous server, so ``arrive``
-        # is a concatenation of ascending runs — timsort merges cheaply
-        t2 = np.argsort(arrive, kind="stable")
-        o2 = _stable_sid_sort(sid2, t2)
-        sid2_s = sid2[o2]
-        arr2_s = arrive[o2]
-        # the stable sort above keeps prior-stage order on ties; the loop
-        # backend keeps ORIGINAL order — repair the (rare) tied runs
-        if _repair_ties(o2, sid2_s, arr2_s, rank=rows):
+        with obs.span("sim.order"):
+            rows = np.flatnonzero(stage.mask[flat.pair_of])
+            po = flat.pair_of[rows]
+            arrive = deliver[rows] + stage.latency[po]
+            srv2 = stage.service[po]
+            sid2 = stage.sid[po]
+            # FIFO departures are monotone per previous server, so
+            # ``arrive`` is a concatenation of ascending runs — timsort
+            # merges cheaply
+            t2 = np.argsort(arrive, kind="stable")
+            o2 = _stable_sid_sort(sid2, t2)
             sid2_s = sid2[o2]
             arr2_s = arrive[o2]
-        starts2 = _segment_starts(sid2_s)
-        srv2_s = srv2[o2]
+            # the stable sort above keeps prior-stage order on ties; the
+            # loop backend keeps ORIGINAL order — repair the (rare) tied runs
+            if _repair_ties(o2, sid2_s, arr2_s, rank=rows):
+                sid2_s = sid2[o2]
+                arr2_s = arrive[o2]
+            starts2 = _segment_starts(sid2_s)
+            srv2_s = srv2[o2]
         w2_s = _pass_waits(arr2_s, srv2_s, starts2, backend)
-        util = max(util, _util_max(arr2_s, srv2_s, w2_s, starts2))
-        rows2 = rows[o2]
-        wait[rows2] += w2_s
-        deliver[rows2] = arr2_s + w2_s + srv2_s
-    return _metrics(jobs, flat, wait, deliver, util)
+        with obs.span("sim.reduce"):
+            util = max(util, _util_max(arr2_s, srv2_s, w2_s, starts2))
+            rows2 = rows[o2]
+            wait[rows2] += w2_s
+            deliver[rows2] = arr2_s + w2_s + srv2_s
+    with obs.span("sim.reduce"):
+        return _metrics(jobs, flat, wait, deliver, util)
 
 
 # ---------------------------------------------------------------------------
@@ -814,70 +846,84 @@ def simulate_scan_batch(jobs: Sequence[AppGraph],
     for p in placements:
         p.validate()
 
+    # One loop over the K placements per phase, not one per placement
+    # through every phase: each phase is one host span whatever K is.
     K = len(placements)
-    rows = []                 # per-k state carried between stages
-    u1 = np.empty((K, flat.n_messages))
-    v1 = np.empty_like(u1)
-    for k, p in enumerate(placements):
-        sid1_p, service_p, stages = _route_pairs(cluster, flat, p)
-        order, po_s, starts, r = _round1_order(flat, sid1_p)
-        service = service_p[flat.pair_of]
-        u1[k], v1[k] = _uv_elements(flat.emit_t[r], service_p[po_s], starts)
-        rows.append({"service": service, "stages": stages,
-                     "order": order, "starts": starts})
-
-    w1 = _device_waits(u1, v1, backend)
+    with obs.span("sim.route"):
+        routed = [_route_pairs(cluster, flat, p) for p in placements]
+    with obs.span("sim.order"):
+        ordered = [_round1_order(flat, sid1_p) for sid1_p, _, _ in routed]
+    with obs.span("sim.pack"):
+        u1 = np.empty((K, flat.n_messages))
+        v1 = np.empty_like(u1)
+        for k, ((_, service_p, _), (_, po_s, starts, r)) in enumerate(
+                zip(routed, ordered)):
+            u1[k], v1[k] = _uv_elements(flat.emit_t[r], service_p[po_s],
+                                        starts)
+        u1, v1 = _pad(u1, v1)
+    w1 = _device_scan(u1, v1, flat.n_messages, backend)
     results_state = []
-    for k, st in enumerate(rows):
-        order, starts = st["order"], st["starts"]
-        arr_s, srv_s = flat.emit[order], st["service"][order]
-        w_s = np.asarray(w1[k], dtype=np.float64)
-        util = _util_max(arr_s, srv_s, w_s, starts)
-        wait = np.empty_like(w_s)
-        wait[order] = w_s
-        deliver = flat.emit + wait + st["service"]
-        results_state.append({"wait": wait, "deliver": deliver, "util": util})
+    with obs.span("sim.reduce"):
+        for k, ((_, service_p, _), (order, _, starts, _)) in enumerate(
+                zip(routed, ordered)):
+            service = service_p[flat.pair_of]
+            arr_s, srv_s = flat.emit[order], service[order]
+            w_s = np.asarray(w1[k], dtype=np.float64)
+            util = _util_max(arr_s, srv_s, w_s, starts)
+            wait = np.empty_like(w_s)
+            wait[order] = w_s
+            deliver = flat.emit + wait + service
+            results_state.append({"wait": wait, "deliver": deliver,
+                                  "util": util})
 
-    n_stages = max(len(st["stages"]) for st in rows)
+    n_stages = max(len(stages) for _, _, stages in routed)
     for si in range(n_stages):
         passes: list[dict | None] = [None] * K
-        ragged: list[tuple[np.ndarray, np.ndarray]] = []
-        for k, st in enumerate(rows):
-            if si >= len(st["stages"]):
-                continue
-            stage = st["stages"][si]
-            rs = results_state[k]
-            idx2 = np.flatnonzero(stage.mask[flat.pair_of])
-            if idx2.size == 0:
-                continue
-            po = flat.pair_of[idx2]
-            arrive = rs["deliver"][idx2] + stage.latency[po]
-            srv = stage.service[po]
-            sid2 = stage.sid[po]
-            order = _order_by_server_arrival(sid2, arrive)
-            starts = _segment_starts(sid2[order])
-            u, v = _uv_elements(arrive[order], srv[order], starts)
-            passes[k] = {"idx2": idx2, "arrive": arrive, "srv": srv,
-                         "order": order, "starts": starts,
-                         "row": len(ragged)}
-            ragged.append((u, v))
-        if not ragged:
+        with obs.span("sim.order"):
+            for k, (_, _, stages) in enumerate(routed):
+                if si >= len(stages):
+                    continue
+                stage = stages[si]
+                idx2 = np.flatnonzero(stage.mask[flat.pair_of])
+                if idx2.size == 0:
+                    continue
+                po = flat.pair_of[idx2]
+                arrive = results_state[k]["deliver"][idx2] + stage.latency[po]
+                srv = stage.service[po]
+                sid2 = stage.sid[po]
+                order = _order_by_server_arrival(sid2, arrive)
+                starts = _segment_starts(sid2[order])
+                passes[k] = {"idx2": idx2, "arrive": arrive, "srv": srv,
+                             "order": order, "starts": starts}
+        live = [p2 for p2 in passes if p2 is not None]
+        if not live:
             continue
         # stage rows are ragged (routing differs per placement)
-        ws = _waits_rows(ragged, backend)
-        for k, p2 in enumerate(passes):
-            if p2 is None:
-                continue
-            rs = results_state[k]
-            idx2, order, starts = p2["idx2"], p2["order"], p2["starts"]
-            arr_s, srv_s = p2["arrive"][order], p2["srv"][order]
-            w_s = np.asarray(ws[p2["row"]], dtype=np.float64)
-            rs["util"] = max(rs["util"],
-                             _util_max(arr_s, srv_s, w_s, starts))
-            w_rx = np.empty_like(w_s)
-            w_rx[order] = w_s
-            rs["wait"][idx2] += w_rx
-            rs["deliver"][idx2] = p2["arrive"] + w_rx + p2["srv"]
+        with obs.span("sim.pack"):
+            ragged = []
+            for p2 in live:
+                order = p2["order"]
+                p2["row"] = len(ragged)
+                ragged.append(_uv_elements(p2["arrive"][order],
+                                           p2["srv"][order], p2["starts"]))
+            width = max(u.size for u, _ in ragged)
+            u2, v2 = _pad(*_stack_rows(ragged))
+        w2 = _device_scan(u2, v2, width, backend)
+        with obs.span("sim.reduce"):
+            for k, p2 in enumerate(passes):
+                if p2 is None:
+                    continue
+                rs = results_state[k]
+                idx2, order, starts = p2["idx2"], p2["order"], p2["starts"]
+                arr_s, srv_s = p2["arrive"][order], p2["srv"][order]
+                w_s = np.asarray(w2[p2["row"], :idx2.size], dtype=np.float64)
+                rs["util"] = max(rs["util"],
+                                 _util_max(arr_s, srv_s, w_s, starts))
+                w_rx = np.empty_like(w_s)
+                w_rx[order] = w_s
+                rs["wait"][idx2] += w_rx
+                rs["deliver"][idx2] = p2["arrive"] + w_rx + p2["srv"]
 
-    return [_metrics(jobs, flat, rs["wait"], rs["deliver"],
-                     rs["util"]) for rs in results_state]
+    with obs.span("sim.reduce"):
+        return [_metrics(jobs, flat, rs["wait"], rs["deliver"],
+                         rs["util"]) for rs in results_state]

@@ -45,7 +45,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 from typing import Sequence
 
 import numpy as np
@@ -57,18 +56,16 @@ BACKENDS = ("loop", "segmented", "jax", "pallas")
 
 
 def _record_sim(name: str, backend: str, n_msgs: int, n_jobs: int,
-                wall: float, warm: bool, k: int = 1) -> None:
+                warm: bool, k: int = 1) -> None:
     """Per-call provenance on the installed recorder (DESIGN.md §11):
     one instant (timestamped on the caller-set sim clock) + aggregate
-    counters. Call sites guard on ``recorder.enabled`` so the disabled
-    path never reads the wall clock."""
+    counters. Call sites guard on ``recorder.enabled``."""
     rec = obs.current()
     m = rec.metrics
     m.counter(f"sim.calls.{backend}").inc()
     m.counter("sim.msgs").inc(n_msgs * k)
-    m.counter("sim.wall_s", wall=True).inc(wall)
     rec.instant(name, cat=obs.CAT_SIM, track="sim", backend=backend,
-                n_msgs=n_msgs, n_jobs=n_jobs, k=k, warm=warm, wall=wall)
+                n_msgs=n_msgs, n_jobs=n_jobs, k=k, warm=warm)
 
 
 @dataclasses.dataclass
@@ -153,19 +150,18 @@ def simulate(jobs: Sequence[AppGraph], placement: Placement,
     implementation (module docstring); all backends agree on the metrics to
     float tolerance.
     """
-    backend = resolve_backend(backend)
-    traced = obs.current().enabled
-    t0 = time.perf_counter() if traced else 0.0
-    if backend == "loop":
-        res = _simulate_loop(jobs, placement, cluster, count_scale)
-    else:
-        from . import sim_scan
-        res = sim_scan.simulate_scan(jobs, placement, cluster, count_scale,
-                                     backend=backend)
-    if traced:
-        _record_sim("simulate", backend, res.n_messages, len(jobs),
-                    time.perf_counter() - t0, warm=False)
-    return res
+    with obs.span("sim.call"):
+        backend = resolve_backend(backend)
+        if backend == "loop":
+            res = _simulate_loop(jobs, placement, cluster, count_scale)
+        else:
+            from . import sim_scan
+            res = sim_scan.simulate_scan(jobs, placement, cluster, count_scale,
+                                         backend=backend)
+        if obs.current().enabled:
+            _record_sim("simulate", backend, res.n_messages, len(jobs),
+                        warm=False)
+        return res
 
 
 def simulate_batch(jobs: Sequence[AppGraph], placements: Sequence[Placement],
@@ -182,21 +178,19 @@ def simulate_batch(jobs: Sequence[AppGraph], placements: Sequence[Placement],
     flat-message cache (flattening is the dominant host cost).
     """
     backend = resolve_backend(backend)
-    if backend in ("jax", "pallas"):
-        from . import sim_scan
-        traced = obs.current().enabled
-        t0 = time.perf_counter() if traced else 0.0
+    if backend not in ("jax", "pallas"):
+        # numpy fallback: each per-placement simulate records itself
+        return [simulate(jobs, p, cluster, count_scale, backend=backend)
+                for p in placements]
+    from . import sim_scan
+    with obs.span("sim.call"):
         out = sim_scan.simulate_scan_batch(jobs, placements, cluster,
                                            count_scale, backend=backend)
-        if traced:
+        if obs.current().enabled:
             _record_sim("simulate_batch", backend,
                         out[0].n_messages if out else 0, len(jobs),
-                        time.perf_counter() - t0, warm=False,
-                        k=len(placements))
+                        warm=False, k=len(placements))
         return out
-    # numpy fallback: each per-placement simulate records itself
-    return [simulate(jobs, p, cluster, count_scale, backend=backend)
-            for p in placements]
 
 
 class SimHandle:
@@ -228,40 +222,37 @@ class SimHandle:
 
     def simulate(self, jobs: Sequence[AppGraph],
                  placement: Placement) -> SimResult:
-        traced = obs.current().enabled
-        warm = self._flat is not None
-        t0 = time.perf_counter() if traced else 0.0
-        if self.backend == "loop":
-            res = _simulate_loop(jobs, placement, self.cluster,
-                                 self.count_scale)
-        else:
-            from . import sim_scan
-            res = sim_scan.simulate_scan(
-                jobs, placement, self.cluster, self.count_scale,
-                backend=self.backend, flat=self._warm_flat(jobs))
-        if traced:
-            _record_sim("simulate", self.backend, res.n_messages,
-                        len(jobs), time.perf_counter() - t0, warm=warm)
-        return res
+        with obs.span("sim.call"):
+            warm = self._flat is not None
+            if self.backend == "loop":
+                res = _simulate_loop(jobs, placement, self.cluster,
+                                     self.count_scale)
+            else:
+                from . import sim_scan
+                res = sim_scan.simulate_scan(
+                    jobs, placement, self.cluster, self.count_scale,
+                    backend=self.backend, flat=self._warm_flat(jobs))
+            if obs.current().enabled:
+                _record_sim("simulate", self.backend, res.n_messages,
+                            len(jobs), warm=warm)
+            return res
 
     def simulate_batch(self, jobs: Sequence[AppGraph],
                        placements: Sequence[Placement]) -> list[SimResult]:
-        if self.backend in ("jax", "pallas"):
-            from . import sim_scan
-            traced = obs.current().enabled
+        if self.backend not in ("jax", "pallas"):
+            # numpy fallback: each per-placement simulate records itself
+            return [self.simulate(jobs, p) for p in placements]
+        from . import sim_scan
+        with obs.span("sim.call"):
             warm = self._flat is not None
-            t0 = time.perf_counter() if traced else 0.0
             out = sim_scan.simulate_scan_batch(
                 jobs, placements, self.cluster, self.count_scale,
                 backend=self.backend, flat=self._warm_flat(jobs))
-            if traced:
+            if obs.current().enabled:
                 _record_sim("simulate_batch", self.backend,
                             out[0].n_messages if out else 0, len(jobs),
-                            time.perf_counter() - t0, warm=warm,
-                            k=len(placements))
+                            warm=warm, k=len(placements))
             return out
-        # numpy fallback: each per-placement simulate records itself
-        return [self.simulate(jobs, p) for p in placements]
 
 
 def _simulate_loop(jobs: Sequence[AppGraph], placement: Placement,

@@ -6,7 +6,11 @@ Public surface:
   metrics  — Metrics registry (Counter / Gauge / Histogram / Series)
   export   — Chrome trace-event (Perfetto) + CSV exporters, trace-schema
              validators, ``python -m repro.obs.export`` CLI
+  host     — host-clock spans, always on: ``span(name)`` (also a
+             ``jax.profiler.TraceAnnotation`` under a profiler session),
+             ``host_summary(t0, t1)``, ``host_records()``
 """
+from .host import HostSpans, SpanTotals, host_records, host_summary, span
 from .metrics import Counter, Gauge, Histogram, Metrics, Series
 from .recorder import (CAT_METRIC, CAT_SCHED, CAT_SEARCH, CAT_SIM, NULL,
                        NullRecorder, Recorder, TraceEvent, current, from_env,
@@ -15,6 +19,7 @@ from .recorder import (CAT_METRIC, CAT_SCHED, CAT_SEARCH, CAT_SIM, NULL,
 __all__ = [
     "CAT_METRIC", "CAT_SCHED", "CAT_SEARCH", "CAT_SIM",
     "Counter", "Gauge", "Histogram", "Metrics", "Series",
+    "HostSpans", "SpanTotals", "host_records", "host_summary", "span",
     "NULL", "NullRecorder", "Recorder", "TraceEvent",
     "current", "from_env", "install", "recording",
 ]

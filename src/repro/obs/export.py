@@ -41,12 +41,11 @@ _PHASES = (INSTANT, SPAN, COUNTER)
 # ---------------------------------------------------------------------------
 # Chrome trace-event JSON
 # ---------------------------------------------------------------------------
-def to_chrome(doc: dict, include_wall: bool = False) -> dict:
+def to_chrome(doc: dict) -> dict:
     """Native document -> Chrome trace-event JSON object (Perfetto).
 
     Deterministic: pids/tids are assigned in sorted label order and the
-    native event order is preserved. With ``include_wall`` every event
-    that recorded a wall duration gains ``args.wall_s``.
+    native event order is preserved.
     """
     events = doc.get("events", [])
     procs = sorted({e.get("proc", "main") for e in events})
@@ -69,8 +68,6 @@ def to_chrome(doc: dict, include_wall: bool = False) -> dict:
         proc = e.get("proc", "main")
         track = e.get("track") or e["cat"]
         args = dict(e.get("args") or {})
-        if include_wall and "wall" in e:
-            args["wall_s"] = e["wall"]
         ce = {"name": e["name"], "cat": e["cat"], "ph": e["ph"],
               "ts": e["ts"] * _S_TO_US, "pid": pid_of[proc],
               "tid": tid_of[(proc, track)], "args": args}
@@ -215,8 +212,6 @@ def main(argv: Optional[list[str]] = None) -> None:
                     help="output path (default: stdout)")
     ap.add_argument("--series", default="util.",
                     help="csv: counter-name prefix to export")
-    ap.add_argument("--wall", action="store_true",
-                    help="include wall-clock fields in the export")
     args = ap.parse_args(argv)
 
     try:
@@ -235,8 +230,7 @@ def main(argv: Optional[list[str]] = None) -> None:
               f"({len(doc.get('events', []))} events)", file=sys.stderr)
         return
     if args.format == "perfetto":
-        text = json.dumps(to_chrome(doc, include_wall=args.wall),
-                          indent=1, sort_keys=True)
+        text = json.dumps(to_chrome(doc), indent=1, sort_keys=True)
     else:
         text = to_csv(doc, series_prefix=args.series)
     if args.out:

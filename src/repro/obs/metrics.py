@@ -21,11 +21,8 @@ primitive instrument kinds:
 
 Everything is plain Python + numpy, no locks (the schedulers are
 single-threaded), and every summary is a deterministic function of the
-recorded values: two seeded runs dump byte-identical JSON. Instruments
-created with ``wall=True`` hold wall-clock-derived values (evals/s,
-simulate wall spans) and are excluded from :meth:`Metrics.to_dict` by
-default so the determinism contract survives instrumentation that
-happens to measure real time.
+recorded values: two seeded runs dump byte-identical JSON. Nothing
+here reads the wall clock; host time is ``repro.obs.host``'s.
 """
 from __future__ import annotations
 
@@ -45,11 +42,10 @@ def _round(x: float) -> float:
 class Counter:
     """Accumulated total + increment count."""
 
-    __slots__ = ("name", "wall", "total", "n")
+    __slots__ = ("name", "total", "n")
 
-    def __init__(self, name: str, wall: bool = False):
+    def __init__(self, name: str):
         self.name = name
-        self.wall = wall
         self.total = 0.0
         self.n = 0
 
@@ -64,11 +60,10 @@ class Counter:
 class Gauge:
     """Last-value sample with an optional (time, value) step history."""
 
-    __slots__ = ("name", "wall", "value", "n", "times", "values")
+    __slots__ = ("name", "value", "n", "times", "values")
 
-    def __init__(self, name: str, wall: bool = False):
+    def __init__(self, name: str):
         self.name = name
-        self.wall = wall
         self.value = 0.0
         self.n = 0
         self.times: list[float] = []
@@ -92,11 +87,10 @@ class Histogram:
     """Scalar sample distribution; keeps the raw samples (they are the
     p99 inputs the scheduler's stats need, and runs are short)."""
 
-    __slots__ = ("name", "wall", "samples")
+    __slots__ = ("name", "samples")
 
-    def __init__(self, name: str, wall: bool = False):
+    def __init__(self, name: str):
         self.name = name
-        self.wall = wall
         self.samples: list[float] = []
 
     def observe(self, v: Number) -> None:
@@ -131,11 +125,10 @@ class Series:
     equally — the uniform-weighting contract of DESIGN.md §11).
     """
 
-    __slots__ = ("name", "wall", "times", "values")
+    __slots__ = ("name", "times", "values")
 
-    def __init__(self, name: str, wall: bool = False):
+    def __init__(self, name: str):
         self.name = name
-        self.wall = wall
         self.times: list[float] = []
         self.values: list[np.ndarray] = []
 
@@ -173,34 +166,33 @@ class Metrics:
     Accessors are get-or-create; asking for an existing name with a
     different kind raises (names are the schema). ``to_dict`` yields the
     flat metrics JSON merged into the ``BENCH_*.json`` artifacts —
-    sorted names, summaries only, wall-derived instruments excluded
-    unless ``include_wall``.
+    sorted names, summaries only.
     """
 
     def __init__(self) -> None:
         self._instruments: dict[str, object] = {}
 
-    def _get(self, cls, name: str, wall: bool):
+    def _get(self, cls, name: str):
         inst = self._instruments.get(name)
         if inst is None:
-            inst = cls(name, wall=wall)
+            inst = cls(name)
             self._instruments[name] = inst
         elif not isinstance(inst, cls):
             raise TypeError(f"metric {name!r} already registered as "
                             f"{type(inst).__name__}, not {cls.__name__}")
         return inst
 
-    def counter(self, name: str, wall: bool = False) -> Counter:
-        return self._get(Counter, name, wall)
+    def counter(self, name: str) -> Counter:
+        return self._get(Counter, name)
 
-    def gauge(self, name: str, wall: bool = False) -> Gauge:
-        return self._get(Gauge, name, wall)
+    def gauge(self, name: str) -> Gauge:
+        return self._get(Gauge, name)
 
-    def histogram(self, name: str, wall: bool = False) -> Histogram:
-        return self._get(Histogram, name, wall)
+    def histogram(self, name: str) -> Histogram:
+        return self._get(Histogram, name)
 
-    def series(self, name: str, wall: bool = False) -> Series:
-        return self._get(Series, name, wall)
+    def series(self, name: str) -> Series:
+        return self._get(Series, name)
 
     def __contains__(self, name: str) -> bool:
         return name in self._instruments
@@ -217,7 +209,6 @@ class Metrics:
         return {name: self._instruments[name].n
                 for name in sorted(self._instruments)}
 
-    def to_dict(self, include_wall: bool = False) -> dict:
+    def to_dict(self) -> dict:
         return {name: inst.summary()
-                for name, inst in sorted(self._instruments.items())
-                if include_wall or not inst.wall}
+                for name, inst in sorted(self._instruments.items())}

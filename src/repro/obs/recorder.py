@@ -13,12 +13,10 @@ Event model (native format ``repro-trace-v1``):
 * ``phase``: ``"i"`` (instant), ``"X"`` (complete span with a sim-time
   duration), ``"C"`` (counter sample) — the same phase letters the
   Chrome trace-event exporter maps through 1:1.
-* ``ts`` / ``dur``: simulation seconds. No event ever reads the wall
-  clock for its timestamp, so two seeded runs record byte-identical
-  streams. An *optional* ``wall`` field carries a wall-clock duration
-  (how long a simulate call or a search actually took) and is excluded
-  from dumps unless asked for — determinism by default, profiling on
-  demand.
+* ``ts`` / ``dur``: simulation seconds. No event reads the wall clock,
+  so two seeded runs record byte-identical streams. How long the host
+  took is the host-clock spans' business (``repro.obs.host``), which
+  never enter this stream.
 * ``proc`` / ``track``: the Perfetto process/thread the exporter places
   the event on (one process per subsystem or benchmark leg, one track
   per rack / level / event class).
@@ -67,9 +65,7 @@ _DEF_RING = 256
 
 @dataclasses.dataclass
 class TraceEvent:
-    """One structured record. ``ts``/``dur`` are simulation seconds;
-    ``wall`` is an optional wall-clock duration in seconds (profiling
-    only — excluded from dumps by default)."""
+    """One structured record; ``ts``/``dur`` are simulation seconds."""
 
     name: str
     cat: str
@@ -79,16 +75,12 @@ class TraceEvent:
     proc: str = "main"
     track: str = ""
     args: Optional[dict] = None
-    wall: Optional[float] = None
 
-    def to_dict(self, include_wall: bool = False) -> dict:
-        d = {"name": self.name, "cat": self.cat, "ph": self.ph,
-             "ts": self.ts, "dur": self.dur, "proc": self.proc,
-             "track": self.track or self.cat,
-             "args": self.args if self.args is not None else {}}
-        if include_wall and self.wall is not None:
-            d["wall"] = self.wall
-        return d
+    def to_dict(self) -> dict:
+        return {"name": self.name, "cat": self.cat, "ph": self.ph,
+                "ts": self.ts, "dur": self.dur, "proc": self.proc,
+                "track": self.track or self.cat,
+                "args": self.args if self.args is not None else {}}
 
     def line(self) -> str:
         """Compact one-line rendering for flight-recorder dumps."""
@@ -130,22 +122,21 @@ class Recorder:
     # -- emission ----------------------------------------------------------
     def instant(self, name: str, cat: str = CAT_SCHED, *,
                 ts: Optional[float] = None, track: str = "",
-                wall: Optional[float] = None, **args) -> None:
+                **args) -> None:
         if not self.enabled:
             return
         self.events.append(TraceEvent(
             name=name, cat=cat, ph=INSTANT,
             ts=self.clock if ts is None else ts, proc=self.process,
-            track=track, args=args or None, wall=wall))
+            track=track, args=args or None))
 
     def span(self, name: str, cat: str = CAT_SCHED, *, ts: float,
-             dur: float, track: str = "", wall: Optional[float] = None,
-             **args) -> None:
+             dur: float, track: str = "", **args) -> None:
         if not self.enabled:
             return
         self.events.append(TraceEvent(
             name=name, cat=cat, ph=SPAN, ts=ts, dur=max(dur, 0.0),
-            proc=self.process, track=track, args=args or None, wall=wall))
+            proc=self.process, track=track, args=args or None))
 
     def counter(self, name: str, value, cat: str = CAT_METRIC, *,
                 ts: Optional[float] = None, track: str = "") -> None:
@@ -163,31 +154,27 @@ class Recorder:
     def n_events(self) -> int:
         return len(self.events)
 
-    def dump(self, extra_metrics: Optional[dict] = None,
-             include_wall: bool = False) -> dict:
+    def dump(self, extra_metrics: Optional[dict] = None) -> dict:
         """Native-format document: events + metrics registries.
 
         ``extra_metrics`` maps namespace -> :class:`Metrics` (e.g. one
         per scheduler run) merged next to the recorder's own registry
-        under ``"metrics"``. Deterministic: sorted keys, wall-clock
-        fields excluded unless ``include_wall``.
+        under ``"metrics"``. Deterministic: sorted keys, no wall clock.
         """
-        metrics = {"recorder": self.metrics.to_dict(include_wall)}
+        metrics = {"recorder": self.metrics.to_dict()}
         for ns, reg in (extra_metrics or {}).items():
-            metrics[ns] = (reg.to_dict(include_wall)
+            metrics[ns] = (reg.to_dict()
                            if isinstance(reg, Metrics) else dict(reg))
         return {
             "format": FORMAT,
             "clock": "sim-seconds",
             "mode": self.mode,
-            "events": [e.to_dict(include_wall) for e in self.events],
+            "events": [e.to_dict() for e in self.events],
             "metrics": metrics,
         }
 
-    def dump_json(self, extra_metrics: Optional[dict] = None,
-                  include_wall: bool = False) -> str:
-        return json.dumps(self.dump(extra_metrics, include_wall),
-                          indent=1, sort_keys=True)
+    def dump_json(self, extra_metrics: Optional[dict] = None) -> str:
+        return json.dumps(self.dump(extra_metrics), indent=1, sort_keys=True)
 
     # -- flight recorder ---------------------------------------------------
     def flight_lines(self, n: int = _DEF_RING) -> list[str]:

@@ -23,6 +23,7 @@ from typing import Iterable, Optional
 
 import numpy as np
 
+from .. import obs
 from ..core.graphs import AppGraph
 from .cells import GLOBAL_CELL, FleetCell
 from .events import DEPARTURE, Event
@@ -174,21 +175,22 @@ class WorkClock:
         holds pod-spanning jobs re-clocks at the pod, and only jobs that
         span pods (or cells, in flat mode) force one global re-simulate
         (their contention couples the domains they touch)."""
-        f = self.f
-        fab = f.fabric
-        if fab.n_cells == 1:
-            self.reclock()
-            return
-        dirty = fab.dirty
-        fab.dirty = set()
-        if not dirty:
-            return
-        if fab.n_spanning or GLOBAL_CELL in dirty:
-            f.metrics.counter("sched.cell_escalations").inc()
-            self.reclock()
-            return
-        for cid in fab.reclock_domains(dirty):
-            self.reclock_cell(fab.cells[cid])
+        with obs.span("sched.reclock"):
+            f = self.f
+            fab = f.fabric
+            if fab.n_cells == 1:
+                self.reclock()
+                return
+            dirty = fab.dirty
+            fab.dirty = set()
+            if not dirty:
+                return
+            if fab.n_spanning or GLOBAL_CELL in dirty:
+                f.metrics.counter("sched.cell_escalations").inc()
+                self.reclock()
+                return
+            for cid in fab.reclock_domains(dirty):
+                self.reclock_cell(fab.cells[cid])
 
     def reclock_cell(self, cell: FleetCell, res=None) -> None:
         """Re-key one cell's resident jobs from the cell's warm handle.

@@ -496,34 +496,42 @@ class FleetScheduler:
                 # events in the heap. Stale mode keeps the full path
                 # (its rare stale events DID advance the clock).
                 return ev
-        self.now = max(self.now, ev.time)
-        rec = self.recorder
-        if rec.enabled:
-            rec.set_clock(self.now)
-        if self.reclock:
-            self.clock.advance()
-        if ev.kind == ARRIVAL:
-            self._arrivals_pending -= 1
-            self.admission.handle_arrival(self.jobs[ev.job_id])
-        elif ev.kind == DEPARTURE:
-            self._handle_departure(ev)
-        elif ev.kind == NODE_FAIL:
-            self.recovery.node_fail(ev)
-        elif ev.kind == NODE_RECOVER:
-            self.recovery.node_recover(ev)
-        elif ev.kind == DRAIN:
-            self.recovery.drain(ev)
-        elif ev.kind == ADMIT:
-            self.admission.scheduled = False
-            if self.admission.admit_batch():
-                self.clock.reclock_fleet()
+        # the root host span of everything this event does; the phase
+        # spans inside it (sched.admission, sched.reclock,
+        # sched.remap_pass) are named apart from every event kind
+        with obs.span(f"sched.{ev.kind}"):
+            self.now = max(self.now, ev.time)
+            rec = self.recorder
+            if rec.enabled:
+                rec.set_clock(self.now)
+            if self.reclock:
+                self.clock.advance()
+            if ev.kind == ARRIVAL:
+                self._arrivals_pending -= 1
+                with obs.span("sched.admission"):
+                    self.admission.handle_arrival(self.jobs[ev.job_id])
+            elif ev.kind == DEPARTURE:
+                self._handle_departure(ev)
+            elif ev.kind == NODE_FAIL:
+                self.recovery.node_fail(ev)
+            elif ev.kind == NODE_RECOVER:
+                self.recovery.node_recover(ev)
+            elif ev.kind == DRAIN:
+                self.recovery.drain(ev)
+            elif ev.kind == ADMIT:
+                self.admission.scheduled = False
+                with obs.span("sched.admission"):
+                    placed = self.admission.admit_batch()
+                if placed:
+                    self.clock.reclock_fleet()
+                    self.remap.maybe_schedule()
+            elif ev.kind == TRAFFIC:
+                self.autoscale.on_traffic(ev)
+            elif ev.kind == REMAP:
+                self.remap.scheduled = False
+                with obs.span("sched.remap_pass"):
+                    self._remap_pass()
                 self.remap.maybe_schedule()
-        elif ev.kind == TRAFFIC:
-            self.autoscale.on_traffic(ev)
-        elif ev.kind == REMAP:
-            self.remap.scheduled = False
-            self._remap_pass()
-            self.remap.maybe_schedule()
         return ev
 
     def run(self, until: Optional[float] = None) -> FleetStats:
@@ -573,7 +581,8 @@ class FleetScheduler:
             return
         self.depart(ev.job_id, now=self.now)
         # departures free cores — drain the FIFO head while it fits
-        placed_any = self.admission.drain_pending()
+        with obs.span("sched.admission"):
+            placed_any = self.admission.drain_pending()
         if self.reclock:
             # one simulate covers the drained jobs AND the survivors'
             # speed-up now that the departed job's traffic is gone

@@ -25,7 +25,6 @@ backend.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Optional, Sequence, Union
 
 import numpy as np
@@ -141,117 +140,113 @@ def search_placement(jobs: Sequence[AppGraph], cluster: ClusterTopology,
     (seed strategies run against scratch copies); claiming the winning
     cores is the strategy adapter's job (``repro.search.strategy``).
     """
-    seed_fn, seed_name = _resolve_seed(seed)
-    rec = obs.current()
-    t0_wall = time.perf_counter() if rec.enabled else 0.0
-    if rec.enabled:
-        rec.instant("search_begin", cat=obs.CAT_SEARCH, track="search",
-                    seed=seed_name, budget=budget, population=population,
-                    anneal=anneal, n_jobs=len(jobs))
-    # offline cores (dead / draining nodes) are as unusable as occupied ones
-    base_used = ((tracker.used | tracker.offline).copy() if tracker is not None
-                 else np.zeros(cluster.n_cores, dtype=bool))
-    usable = ~base_used
-    scale = (objective_scale if objective_scale is not None
-             else auto_objective_scale(jobs, target_msgs))
-    rng = np.random.default_rng(rng_seed)
+    with obs.span("search.run"):
+        seed_fn, seed_name = _resolve_seed(seed)
+        rec = obs.current()
+        if rec.enabled:
+            rec.instant("search_begin", cat=obs.CAT_SEARCH, track="search",
+                        seed=seed_name, budget=budget, population=population,
+                        anneal=anneal, n_jobs=len(jobs))
+        # offline cores (dead / draining nodes) are as unusable as occupied ones
+        base_used = ((tracker.used | tracker.offline).copy() if tracker is not None
+                     else np.zeros(cluster.n_cores, dtype=bool))
+        usable = ~base_used
+        scale = (objective_scale if objective_scale is not None
+                 else auto_objective_scale(jobs, target_msgs))
+        rng = np.random.default_rng(rng_seed)
 
-    # -- initial population: the named seed + the one-shot portfolio -------
-    names = [seed_name]
-    fns = [seed_fn]
-    if multi_seed:
-        for name in ONE_SHOT_STRATEGIES:
-            if name != seed_name:
-                names.append(name)
-                fns.append(STRATEGIES[name])
-        # budget counts every placement scored, seeds included — a tiny
-        # budget trims the portfolio rather than silently overshooting
-        names, fns = names[:max(1, budget)], fns[:max(1, budget)]
-    states: list[SearchState] = []
-    kept: list[str] = []
-    for name, fn in zip(names, fns):
-        scratch = FreeCoreTracker(cluster, occupied=base_used)
-        try:
-            pl = fn(jobs, cluster, scratch)
-        except RuntimeError:
-            if name == seed_name:
-                raise  # the anchor seed must fit — mirrors one-shot behaviour
-            continue  # a portfolio member that cannot place this set is skipped
-        states.append(SearchState.from_placement(cluster, pl, usable))
-        kept.append(name)
-    scores = _score(jobs, [s.placement() for s in states], cluster, scale,
-                    backend)
-    evaluations = len(scores)
-    seeds_scored = dict(zip(kept, scores))
-    seed_objective = scores[0]
-    best_i = min(range(len(scores)), key=lambda i: (scores[i], i))
-    best, best_score = states[best_i], scores[best_i]
-    cur, cur_score = best, best_score
-    sizes = domain_sizes(cluster)
-    trajectory: list[tuple] = []
-    if best_i != 0:
-        trajectory.append((evaluations, ("seed", kept[best_i]), best_score))
-    if rec.enabled:
-        rec.instant("search_seeds", cat=obs.CAT_SEARCH, track="search",
-                    n_seeds=len(kept), best_seed=kept[best_i],
-                    best_score=best_score, evals=evaluations)
+        # -- initial population: the named seed + the one-shot portfolio -------
+        names = [seed_name]
+        fns = [seed_fn]
+        if multi_seed:
+            for name in ONE_SHOT_STRATEGIES:
+                if name != seed_name:
+                    names.append(name)
+                    fns.append(STRATEGIES[name])
+            # budget counts every placement scored, seeds included — a tiny
+            # budget trims the portfolio rather than silently overshooting
+            names, fns = names[:max(1, budget)], fns[:max(1, budget)]
+        states: list[SearchState] = []
+        kept: list[str] = []
+        for name, fn in zip(names, fns):
+            scratch = FreeCoreTracker(cluster, occupied=base_used)
+            try:
+                pl = fn(jobs, cluster, scratch)
+            except RuntimeError:
+                if name == seed_name:
+                    raise  # the anchor seed must fit — mirrors one-shot behaviour
+                continue  # a portfolio member that cannot place this set is skipped
+            states.append(SearchState.from_placement(cluster, pl, usable))
+            kept.append(name)
+        scores = _score(jobs, [s.placement() for s in states], cluster, scale,
+                        backend)
+        evaluations = len(scores)
+        seeds_scored = dict(zip(kept, scores))
+        seed_objective = scores[0]
+        best_i = min(range(len(scores)), key=lambda i: (scores[i], i))
+        best, best_score = states[best_i], scores[best_i]
+        cur, cur_score = best, best_score
+        sizes = domain_sizes(cluster)
+        trajectory: list[tuple] = []
+        if best_i != 0:
+            trajectory.append((evaluations, ("seed", kept[best_i]), best_score))
+        if rec.enabled:
+            rec.instant("search_seeds", cat=obs.CAT_SEARCH, track="search",
+                        n_seeds=len(kept), best_seed=kept[best_i],
+                        best_score=best_score, evals=evaluations)
 
-    # -- refinement rounds -------------------------------------------------
-    rounds = max(0, (budget - evaluations) // max(population, 1))
-    temps = _temperature_schedule(rounds, seed_objective, t0_frac, t_end_frac)
-    for rnd in range(rounds):
-        base = cur if anneal else best
-        cands = neighbours(rng, base, population,
-                           allow_cross_job=allow_cross_job, sizes=sizes)
-        if not cands:
-            break  # no legal move exists (e.g. one 1-process job, full cluster)
-        cand_states = [s for _, s in cands]
-        cand_scores = _score(jobs, [s.placement() for s in cand_states],
-                             cluster, scale, backend)
-        evaluations += len(cand_scores)
-        if anneal:
-            pick = _boltzmann_pick(rng, cur_score, cand_scores, temps[rnd])
-            if pick is not None:
+        # -- refinement rounds -------------------------------------------------
+        rounds = max(0, (budget - evaluations) // max(population, 1))
+        temps = _temperature_schedule(rounds, seed_objective, t0_frac, t_end_frac)
+        for rnd in range(rounds):
+            base = cur if anneal else best
+            cands = neighbours(rng, base, population,
+                               allow_cross_job=allow_cross_job, sizes=sizes)
+            if not cands:
+                break  # no legal move exists (e.g. one 1-process job, full cluster)
+            cand_states = [s for _, s in cands]
+            cand_scores = _score(jobs, [s.placement() for s in cand_states],
+                                 cluster, scale, backend)
+            evaluations += len(cand_scores)
+            if anneal:
+                pick = _boltzmann_pick(rng, cur_score, cand_scores, temps[rnd])
+                if pick is not None:
+                    cur, cur_score = cand_states[pick], cand_scores[pick]
+            else:
+                pick = min(range(len(cand_scores)),
+                           key=lambda i: (cand_scores[i], i))
+                if cand_scores[pick] >= best_score:
+                    if rec.enabled:
+                        rec.instant("search_reject", cat=obs.CAT_SEARCH,
+                                    track="search", evals=evaluations,
+                                    best_score=best_score)
+                    continue
                 cur, cur_score = cand_states[pick], cand_scores[pick]
-        else:
-            pick = min(range(len(cand_scores)),
-                       key=lambda i: (cand_scores[i], i))
-            if cand_scores[pick] >= best_score:
+            if cur_score < best_score:
+                best, best_score = cur, cur_score
+                trajectory.append((evaluations, cands[pick][0].describe(),
+                                   best_score))
                 if rec.enabled:
-                    rec.instant("search_reject", cat=obs.CAT_SEARCH,
+                    rec.instant("search_accept", cat=obs.CAT_SEARCH,
                                 track="search", evals=evaluations,
-                                best_score=best_score)
-                continue
-            cur, cur_score = cand_states[pick], cand_scores[pick]
-        if cur_score < best_score:
-            best, best_score = cur, cur_score
-            trajectory.append((evaluations, cands[pick][0].describe(),
-                               best_score))
-            if rec.enabled:
-                rec.instant("search_accept", cat=obs.CAT_SEARCH,
-                            track="search", evals=evaluations,
-                            move=str(trajectory[-1][1]), score=best_score)
-        elif rec.enabled:
-            rec.instant("search_reject", cat=obs.CAT_SEARCH, track="search",
-                        evals=evaluations, best_score=best_score)
+                                move=str(trajectory[-1][1]), score=best_score)
+            elif rec.enabled:
+                rec.instant("search_reject", cat=obs.CAT_SEARCH, track="search",
+                            evals=evaluations, best_score=best_score)
 
-    if rec.enabled:
-        wall = time.perf_counter() - t0_wall
-        rec.metrics.counter("search.evals").inc(evaluations)
-        rec.metrics.counter("search.accepts").inc(len(trajectory))
-        rec.metrics.gauge("search.evals_per_s", wall=True).set(
-            evaluations / wall if wall > 0 else 0.0)
-        rec.instant("search_end", cat=obs.CAT_SEARCH, track="search",
-                    evals=evaluations, accepted=len(trajectory),
-                    objective=best_score, seed_objective=seed_objective,
-                    wall=wall)
-    return SearchResult(
-        placement=best.placement(), objective=best_score,
-        seed_objective=seed_objective, seed_name=seed_name,
-        evaluations=evaluations,
-        accepted=len(trajectory),
-        trajectory=trajectory, seeds_scored=seeds_scored,
-        objective_scale=scale)
+        if rec.enabled:
+            rec.metrics.counter("search.evals").inc(evaluations)
+            rec.metrics.counter("search.accepts").inc(len(trajectory))
+            rec.instant("search_end", cat=obs.CAT_SEARCH, track="search",
+                        evals=evaluations, accepted=len(trajectory),
+                        objective=best_score, seed_objective=seed_objective)
+        return SearchResult(
+            placement=best.placement(), objective=best_score,
+            seed_objective=seed_objective, seed_name=seed_name,
+            evaluations=evaluations,
+            accepted=len(trajectory),
+            trajectory=trajectory, seeds_scored=seeds_scored,
+            objective_scale=scale)
 
 
 def _temperature_schedule(rounds: int, seed_objective: float,

@@ -85,14 +85,6 @@ def test_metrics_kind_mismatch_raises():
         m.gauge("x")
 
 
-def test_wall_instruments_excluded_from_dump():
-    m = obs.Metrics()
-    m.counter("sim.calls").inc()
-    m.counter("sim.wall_s", wall=True).inc(0.123)
-    assert set(m.to_dict()) == {"sim.calls"}
-    assert set(m.to_dict(include_wall=True)) == {"sim.calls", "sim.wall_s"}
-
-
 # ---------------------------------------------------------------------------
 # Recorder
 # ---------------------------------------------------------------------------
@@ -129,15 +121,6 @@ def test_install_recording_and_from_env():
     assert obs.from_env({"REPRO_TRACE": "1"}).mode == "full"
     ring = obs.from_env({"REPRO_TRACE": "ring", "REPRO_TRACE_RING": "7"})
     assert ring.mode == "ring" and ring.ring == 7
-
-
-def test_dump_excludes_wall_by_default():
-    rec = obs.Recorder()
-    rec.instant("sim", cat=obs.CAT_SIM, ts=1.0, wall=0.25, backend="loop")
-    doc = rec.dump()
-    assert "wall" not in doc["events"][0]
-    doc_w = rec.dump(include_wall=True)
-    assert doc_w["events"][0]["wall"] == 0.25
 
 
 # ---------------------------------------------------------------------------
@@ -334,9 +317,6 @@ def test_simulator_records_call_provenance():
     assert m.counter("sim.msgs").total > 0
     sims = [e for e in rec.events if e.cat == obs.CAT_SIM]
     assert [e.args.get("warm") for e in sims] == [False, False, True]
-    # the wall field exists on the event but stays out of default dumps
-    assert all(e.wall is not None for e in sims)
-    assert all("wall" not in d for d in rec.dump()["events"])
 
 
 def test_invariant_failure_carries_flight_tail():

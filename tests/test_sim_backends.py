@@ -353,7 +353,7 @@ def test_lindley_scan_rows_ragged(backend):
     kernel's 128-lane tile rows and runs down the rows of a block, with
     segment heads inside it. f32 ``pallas`` holds 1e-5 absolute on
     these O(10) waits; f64 ``jax`` holds 1e-12 relative."""
-    from repro.core.sim_scan import _waits_rows
+    from repro.core.sim_scan import _device_waits, _stack_rows
     rng = np.random.default_rng(0)
     rows = []
     for n in (5, 17, 3, 64):
@@ -366,7 +366,8 @@ def test_lindley_scan_rows_ragged(backend):
     u[rng.random(3000) < 0.01] = -np.inf
     rows.append((u, np.zeros(3000, np.float32)))
     tol = {"pallas": dict(atol=1e-5), "jax": dict(rtol=1e-12, atol=1e-12)}
-    for (u, v), w in zip(rows, _waits_rows(rows, backend)):
+    for (u, v), w in zip(rows, _device_waits(*_stack_rows(rows), backend)):
+        w = w[:u.size]
         cur, ref = 0.0, []
         for i in range(len(u)):
             cur = max(cur + float(u[i]), float(v[i])) if i else 0.0
