@@ -27,7 +27,11 @@ Three implementations of the scan:
 * ``jax``       — a blocked ``jax.lax.associative_scan`` over the ``(u, v)``
   elements, jitted, padded to powers of two to bound recompiles; float64
   under ``jax.enable_x64``. Batches over a leading axis for
-  ``simulate_batch``.
+  ``simulate_batch``. Only ``u`` crosses the host/device link, and only
+  where it is real: every element's ``v`` is 0 and is made on the device;
+  a padded row travels as up to ``_LINK_CHUNKS`` chunks, of which only
+  those holding real elements are sent (a device-resident zero chunk
+  fills the rest) and only those covering the real length come back.
 * ``pallas``    — ``repro.kernels.lindley_scan``: the same elements through
   a blocked Pallas TPU kernel (float32; interpreted off a TPU).
 
@@ -527,9 +531,9 @@ def _segment_starts(sid_s: np.ndarray) -> np.ndarray:
     return starts
 
 
-def _increments(arr_s, srv_s, s_idx):
+def _increments(arr_s, srv_s, s_idx, out=None):
     """X_n per sorted message; 0 at segment heads (fresh server)."""
-    x = np.empty(arr_s.size)
+    x = np.empty(arr_s.size) if out is None else out
     x[0] = 0.0
     np.subtract(arr_s[1:], arr_s[:-1], out=x[1:])       # dA_n
     np.subtract(srv_s[:-1], x[1:], out=x[1:])           # S_{n-1} - dA_n
@@ -578,12 +582,13 @@ def _segmented_waits_numpy(arr_s, srv_s, starts):
     return np.subtract(cs, m, out=cs)
 
 
-def _uv_elements(arr_s, srv_s, starts):
-    """Max-plus scan elements: interior (X_n, 0), segment head (-inf, 0)."""
+def _u_elements(arr_s, srv_s, starts, out=None):
+    """Max-plus scan elements' ``u``: interior (X_n, 0), segment head
+    (-inf, 0). Every element's ``v`` is 0, so only ``u`` is built."""
     s_idx = np.flatnonzero(starts)
-    u = _increments(arr_s, srv_s, s_idx)
+    u = _increments(arr_s, srv_s, s_idx, out)
     u[s_idx] = -np.inf
-    return u, np.zeros(arr_s.size)
+    return u
 
 
 # Device rows are padded to a power of two, and to at least one block of
@@ -591,7 +596,11 @@ def _uv_elements(arr_s, srv_s, starts):
 # kernel's (8, 128) tiles — so a churning live fleet hits a bounded set
 # of compiled shapes.
 _SCAN_BLOCK = 1024
+# A padded jax row crosses the link in at most this many chunks, each at
+# least one block: what lies past the real length wastes at most one chunk.
+_LINK_CHUNKS = 8
 _JAX_SCAN = None
+_ZERO_CHUNKS: dict = {}       # (rows, width) -> device-resident zeros
 
 
 def _combine(a, b):
@@ -630,26 +639,86 @@ def _blocked_scan(u, v):
 
 
 def _jax_scan_fn():
+    """The jitted device scan: the chunks of padded ``u`` rows in, the
+    chunks of their waits out — one program per ``(rows, npad)``. ``v`` is
+    0 on every element (``_u_elements``) and is made here, not sent;
+    past a row's real length nothing is read back, so the elements there
+    do not matter. Each real wait depends only on the elements before it,
+    grouped by the padded shape alone."""
     global _JAX_SCAN
     if _JAX_SCAN is None:
         import jax
         import jax.numpy as jnp
 
         @jax.jit
-        def scan(u, v):
-            return jnp.maximum(*_blocked_scan(u, v))
+        def scan(*chunks):
+            u = jnp.concatenate(chunks, axis=-1)
+            w = jnp.maximum(*_blocked_scan(u, jnp.zeros_like(u)))
+            return jnp.split(w, len(chunks), axis=-1)
 
         _JAX_SCAN = scan
     return _JAX_SCAN
 
 
-def _waits_jax(u: np.ndarray, v: np.ndarray) -> np.ndarray:
-    """The (possibly batched) max-plus scan on the JAX backend, in f64."""
+def _padded(n: int) -> int:
+    """Device row width for ``n`` elements: a power of two, at least one
+    scan block."""
+    return max(_SCAN_BLOCK, 1 << int(n - 1).bit_length())
+
+
+def _chunk_width(npad: int) -> int:
+    """Width of the chunks a padded jax row crosses the link in, a
+    function of ``npad`` alone: ``(rows, npad)`` keeps one program."""
+    return npad // min(_LINK_CHUNKS, npad // _SCAN_BLOCK)
+
+
+def _link_rows(rows: int, n: int) -> np.ndarray:
+    """Host buffer for ``rows`` jax element rows, the longest ``n`` long:
+    chunk-major ``(sent, rows, width)``, only the chunks that hold real
+    elements, each contiguous (one chunk is one copy). The last partial
+    chunk is zero past ``n``."""
+    width = _chunk_width(_padded(n))
+    sent = -(-n // width)
+    buf = np.empty((sent, rows, width))
+    buf[-1, :, n - (sent - 1) * width:] = 0.0
+    return buf
+
+
+def _put_row(buf: np.ndarray, k: int, u: np.ndarray) -> None:
+    """Row ``k`` of a ``_link_rows`` buffer set to ``u``, zero past it."""
+    width = buf.shape[-1]
+    full, rem = divmod(u.size, width)
+    buf[:full, k] = u[:full * width].reshape(full, width)
+    if full < len(buf):
+        buf[full, k, :rem] = u[full * width:]
+        buf[full, k, rem:] = 0.0
+        buf[full + 1:, k] = 0.0
+
+
+def _waits_jax(u: np.ndarray, n: int) -> np.ndarray:
+    """Waits of a ``_link_rows`` buffer's rows on the JAX backend, the
+    first ``n`` of each, in f64. Its chunks cross the link; a cached
+    device-resident zero chunk fills the slots of the padding chunks, and
+    only the output chunks that cover ``n`` come back."""
     import jax
-    import jax.numpy as jnp
+    sent, rows, width = u.shape
+    slots = _padded(n) // width
     with jax.enable_x64(True):
-        w = _jax_scan_fn()(jnp.asarray(u), jnp.asarray(v))
-        return np.asarray(w)
+        zero = _ZERO_CHUNKS.get((rows, width))
+        if zero is None:
+            zero = _ZERO_CHUNKS[rows, width] = jax.device_put(
+                np.zeros((rows, width)))
+        chunks = jax.device_put(list(u)) + [zero] * (slots - sent)
+        out = _jax_scan_fn()(*chunks)[:sent]
+        for c in out:
+            c.copy_to_host_async()
+        w = np.empty((rows, n))
+        for j, c in enumerate(out):
+            w[:, j * width:(j + 1) * width] = np.asarray(c)[:, :n - j * width]
+    _count("sim.device.chunks_sent", sent)
+    _count("sim.device.chunks_total", slots)
+    _count("sim.device.d2h_bytes", u.nbytes)
+    return w
 
 
 def pallas_interpret() -> bool:
@@ -660,33 +729,12 @@ def pallas_interpret() -> bool:
 
 def _pad(u: np.ndarray, v: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     """(batch, n) element rows padded with identity elements ``(0, -inf)``
-    past the tail to a power of two, at least one scan block."""
+    past the tail to a power of two, at least one scan block (the Pallas
+    kernel's rows)."""
     n = u.shape[-1]
-    npad = max(_SCAN_BLOCK, 1 << int(n - 1).bit_length())
-    widths = [(0, 0)] * (u.ndim - 1) + [(0, npad - n)]
+    widths = [(0, 0)] * (u.ndim - 1) + [(0, _padded(n) - n)]
     return (np.pad(u, widths, constant_values=0.0),
             np.pad(v, widths, constant_values=-np.inf))
-
-
-def _device_scan(u: np.ndarray, v: np.ndarray, n: int,
-                 backend: str) -> np.ndarray:
-    """Waits of padded rows on the ``jax`` or ``pallas`` backend, the
-    first ``n`` of each, back on the host in float64. The ``sim.device``
-    span counts the bytes sent (the ``pallas`` kernel takes float32)."""
-    with obs.span("sim.device") as s:
-        s.count((u.size + v.size) * (8 if backend == "jax" else 4))
-        if backend == "jax":
-            w = _waits_jax(u, v)
-        else:
-            from ..kernels.lindley_scan import lindley_scan
-            w = lindley_scan(u, v, interpret=pallas_interpret())
-        return np.asarray(w[..., :n], dtype=np.float64)
-
-
-def _device_waits(u: np.ndarray, v: np.ndarray, backend: str) -> np.ndarray:
-    """Waits of (batch, n) element rows on the ``jax`` or ``pallas``
-    backend, padded with identity elements ``(0, -inf)`` past the tail."""
-    return _device_scan(*_pad(u, v), u.shape[-1], backend)
 
 
 def _stack_rows(rows) -> tuple[np.ndarray, np.ndarray]:
@@ -698,6 +746,48 @@ def _stack_rows(rows) -> tuple[np.ndarray, np.ndarray]:
         u2[i, :u.size] = u
         v2[i, :v.size] = v
     return u2, v2
+
+
+def _pack(rows, backend: str):
+    """Ragged ``u`` rows as the backend's device scan takes them: a
+    ``_link_rows`` buffer on ``jax``; on ``pallas`` the ``(u, v)`` rows
+    padded with the identity past each row's tail."""
+    if backend == "jax":
+        buf = _link_rows(len(rows), max(u.size for u in rows))
+        for k, u in enumerate(rows):
+            _put_row(buf, k, u)
+        return buf
+    return _pad(*_stack_rows([(u, np.zeros(u.size)) for u in rows]))
+
+
+def _device_scan(packed, n: int, backend: str) -> np.ndarray:
+    """Waits of ``_pack``ed rows on the ``jax`` or ``pallas`` backend, the
+    first ``n`` of each, back on the host in float64. The ``sim.device``
+    span counts the bytes sent: the chunks of ``u`` on ``jax``, the
+    padded float32 ``(u, v)`` rows the ``pallas`` kernel takes."""
+    with obs.span("sim.device") as s:
+        if backend == "jax":
+            s.count(packed.nbytes)
+            return _waits_jax(packed, n)
+        from ..kernels.lindley_scan import lindley_scan
+        u, v = packed
+        s.count((u.size + v.size) * 4)
+        w = lindley_scan(u, v, interpret=pallas_interpret())
+        return np.asarray(w[..., :n], dtype=np.float64)
+
+
+def _device_waits(u: np.ndarray, v: np.ndarray, backend: str) -> np.ndarray:
+    """Waits of (batch, n) element rows on the ``jax`` or ``pallas``
+    backend. ``pallas`` takes ``(u, v)``, padded with identity elements
+    ``(0, -inf)`` past the tail. ``jax`` ignores ``v``: the simulator's
+    elements all have ``v = 0`` (``_u_elements``), which its scan makes on
+    the device, and nothing past the tail is read back. Called at
+    ``(batch, power of two)`` it compiles exactly the programs the
+    simulator's calls of that padded shape run, whatever ``v`` holds."""
+    n = u.shape[-1]
+    if backend == "jax":
+        return _device_scan(_pack(list(u), backend), n, backend)
+    return _device_scan(_pad(u, v), n, backend)
 
 
 def _util_max(arr_s, srv_s, w_s, starts) -> float:
@@ -714,10 +804,14 @@ def _pass_waits(arr_s, srv_s, starts, backend: str) -> np.ndarray:
     if backend == "segmented":
         with obs.span("sim.scan"):
             return _segmented_waits_numpy(arr_s, srv_s, starts)
+    n = arr_s.size
     with obs.span("sim.pack"):
-        u, v = _uv_elements(arr_s, srv_s, starts)
-        u, v = _pad(u[None], v[None])
-    return _device_scan(u, v, arr_s.size, backend)[0]
+        if backend == "jax":          # straight into the link's chunks
+            packed = _link_rows(1, n)
+            _u_elements(arr_s, srv_s, starts, out=packed.reshape(-1)[:n])
+        else:
+            packed = _pack([_u_elements(arr_s, srv_s, starts)], backend)
+    return _device_scan(packed, n, backend)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -854,21 +948,17 @@ def simulate_scan_batch(jobs: Sequence[AppGraph],
     with obs.span("sim.order"):
         ordered = [_round1_order(flat, sid1_p) for sid1_p, _, _ in routed]
     with obs.span("sim.pack"):
-        u1 = np.empty((K, flat.n_messages))
-        v1 = np.empty_like(u1)
-        for k, ((_, service_p, _), (_, po_s, starts, r)) in enumerate(
-                zip(routed, ordered)):
-            u1[k], v1[k] = _uv_elements(flat.emit_t[r], service_p[po_s],
-                                        starts)
-        u1, v1 = _pad(u1, v1)
-    w1 = _device_scan(u1, v1, flat.n_messages, backend)
+        u1 = _pack([_u_elements(flat.emit_t[r], service_p[po_s], starts)
+                    for (_, service_p, _), (_, po_s, starts, r)
+                    in zip(routed, ordered)], backend)
+    w1 = _device_scan(u1, flat.n_messages, backend)
     results_state = []
     with obs.span("sim.reduce"):
         for k, ((_, service_p, _), (order, _, starts, _)) in enumerate(
                 zip(routed, ordered)):
             service = service_p[flat.pair_of]
             arr_s, srv_s = flat.emit[order], service[order]
-            w_s = np.asarray(w1[k], dtype=np.float64)
+            w_s = w1[k]
             util = _util_max(arr_s, srv_s, w_s, starts)
             wait = np.empty_like(w_s)
             wait[order] = w_s
@@ -904,11 +994,11 @@ def simulate_scan_batch(jobs: Sequence[AppGraph],
             for p2 in live:
                 order = p2["order"]
                 p2["row"] = len(ragged)
-                ragged.append(_uv_elements(p2["arrive"][order],
-                                           p2["srv"][order], p2["starts"]))
-            width = max(u.size for u, _ in ragged)
-            u2, v2 = _pad(*_stack_rows(ragged))
-        w2 = _device_scan(u2, v2, width, backend)
+                ragged.append(_u_elements(p2["arrive"][order],
+                                          p2["srv"][order], p2["starts"]))
+            width = max(u.size for u in ragged)
+            u2 = _pack(ragged, backend)
+        w2 = _device_scan(u2, width, backend)
         with obs.span("sim.reduce"):
             for k, p2 in enumerate(passes):
                 if p2 is None:
@@ -916,7 +1006,7 @@ def simulate_scan_batch(jobs: Sequence[AppGraph],
                 rs = results_state[k]
                 idx2, order, starts = p2["idx2"], p2["order"], p2["starts"]
                 arr_s, srv_s = p2["arrive"][order], p2["srv"][order]
-                w_s = np.asarray(w2[p2["row"], :idx2.size], dtype=np.float64)
+                w_s = w2[p2["row"], :idx2.size]
                 rs["util"] = max(rs["util"],
                                  _util_max(arr_s, srv_s, w_s, starts))
                 w_rx = np.empty_like(w_s)

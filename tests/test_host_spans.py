@@ -220,8 +220,8 @@ def test_lowered_scan_module_is_named_scan():
     import jax
     import jax.numpy as jnp
     with jax.enable_x64(True):
-        u = jnp.zeros((1, 1024))
-        lowered = sim_scan._jax_scan_fn().lower(u, u)
+        chunk = jnp.zeros((1, 1024))              # one chunk: npad 1024
+        lowered = sim_scan._jax_scan_fn().lower(chunk)
     assert "jit_scan" in lowered.as_text().split("\n", 1)[0]
 
 

@@ -7,6 +7,8 @@ metric; the float32 Pallas kernel is held to a looser tolerance. The
 deliberately-tied workloads at the bottom pin the tie-repair semantics
 that random fuzzing would only hit by accident.
 """
+import time
+
 import numpy as np
 import pytest
 
@@ -394,20 +396,125 @@ def test_jax_backend_is_f64():
     would hide an f32 scan on ~1e-3 s waits): absolute arrival times near
     1e4 s with microsecond services, near saturation, agree with numpy
     ``segmented`` to 1e-12 relative — f32 misses by ~1e-7."""
-    from repro.core.sim_scan import (_pass_waits, _segment_starts,
-                                     _uv_elements, _waits_jax)
+    import jax
+    from repro.core.sim_scan import (_jax_scan_fn, _pack, _pass_waits,
+                                     _segment_starts, _u_elements)
     rng = np.random.default_rng(5)
     sid = np.repeat(np.arange(8), 512)            # 4096: no padding
     arr = np.concatenate([1e4 + k + np.cumsum(rng.exponential(1e-6, 512))
                           for k in range(8)])
     srv = rng.uniform(0.5e-6, 1.4e-6, arr.size)
     starts = _segment_starts(sid)
-    u, v = _uv_elements(arr, srv, starts)
-    assert _waits_jax(u, v).dtype == np.float64
+    with jax.enable_x64(True):                    # every chunk is real
+        out = _jax_scan_fn()(*_pack([_u_elements(arr, srv, starts)], "jax"))
+    assert {c.dtype for c in out} == {np.dtype(np.float64)}
     want = _pass_waits(arr, srv, starts, "segmented")
     got = _pass_waits(arr, srv, starts, "jax")
     assert want.max() > 1e-6                       # queues really build
     np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-18)
+
+
+# Rows up to 2^13 elements cross the jax link in 1024-wide chunks (the
+# padded width over min(8, padded width / 1024) chunks): these lengths
+# leave 1, 2, ..., 8 chunks sent, at a chunk's end and one past it.
+_LINK_CHUNK = 1024
+_LINK_LENGTHS = [k * _LINK_CHUNK + d for k in range(1, 9) for d in (0, 1)][:-1]
+
+
+def _queue_pass(rng, n):
+    """One sorted multi-server round of ``n`` messages near saturation:
+    (arrivals, services, segment heads), four servers."""
+    from repro.core.sim_scan import _segment_starts
+    sid = np.sort(rng.integers(0, 4, n))
+    sid[0] = 0
+    arr = rng.uniform(0.0, n / 4, n)
+    arr = arr[np.lexsort((arr, sid))]
+    return arr, rng.uniform(0.5, 1.4, n), _segment_starts(sid)
+
+
+def _lindley_loop(arr, srv, starts):
+    """Scalar f64 Lindley waits, server by server in sorted order."""
+    w = np.empty(arr.size)
+    for i in range(arr.size):
+        w[i] = 0.0 if starts[i] else max(
+            0.0, w[i - 1] + srv[i - 1] - (arr[i] - arr[i - 1]))
+    return w
+
+
+@pytest.mark.parametrize("n_rows", [1, 4])
+@pytest.mark.parametrize("n", _LINK_LENGTHS)
+def test_jax_link_chunks_keep_every_wait(n, n_rows):
+    """Only the chunks holding real elements cross the link, and no ``v``
+    row: the waits equal, bit for bit, the padded ``(u, v)`` scan with
+    every chunk sent, match a scalar Lindley loop to 1e-12, and the
+    ``sim.device`` span counts 8 bytes per element of the chunks sent.
+    One row goes through ``_pass_waits`` (built straight into the link's
+    chunks); four ragged rows, the longest ``n``, through ``_pack`` as a
+    batched stage does."""
+    import jax
+    import jax.numpy as jnp
+    from repro import obs
+    from repro.core.sim_scan import (_blocked_scan, _device_scan, _pack,
+                                     _pad, _pass_waits, _stack_rows,
+                                     _u_elements)
+    rng = np.random.default_rng(n * 10 + n_rows)
+    lengths = [n, -(-n // 2), n // 3 + 1, 5][:n_rows]
+    passes = [_queue_pass(rng, m) for m in lengths]
+    rows = [_u_elements(*p) for p in passes]
+    t0 = time.perf_counter()
+    with obs.recording() as rec:
+        if n_rows == 1:
+            got = _pass_waits(*passes[0], "jax")[None]
+        else:
+            got = _device_scan(_pack(rows, "jax"), n, "jax")
+    spans = obs.host_summary(t0, time.perf_counter())
+    npad = max(_LINK_CHUNK, 1 << (n - 1).bit_length())
+    sent = -(-n // _LINK_CHUNK)
+    assert spans["sim.device"].count == 8 * n_rows * sent * _LINK_CHUNK
+    counters = {k: rec.metrics.counter("sim.device." + k).total
+                for k in ("chunks_sent", "chunks_total", "d2h_bytes")}
+    assert counters == {"chunks_sent": sent,
+                        "chunks_total": npad // _LINK_CHUNK,
+                        "d2h_bytes": 8 * n_rows * sent * _LINK_CHUNK}
+    u, v = _pad(*_stack_rows([(r, np.zeros(r.size)) for r in rows]))
+    with jax.enable_x64(True):
+        padded = np.asarray(jax.jit(
+            lambda u, v: jnp.maximum(*_blocked_scan(u, v)))(u, v))
+    for k, (m, p) in enumerate(zip(lengths, passes)):
+        np.testing.assert_array_equal(got[k, :m], padded[k, :m])
+        np.testing.assert_allclose(got[k, :m], _lindley_loop(*p),
+                                   rtol=1e-12, atol=1e-12)
+
+
+def test_jax_warm_shape_compiles_nothing_at_any_chunk_count():
+    """Warmed as the benchmark warms (``_device_waits`` of zeros and
+    ``v = -inf`` at ``(rows, npad)``), calls that send any number of that
+    width's chunks build no program: the window compiles nothing."""
+    import jax
+    from repro.core.sim_scan import (_device_scan, _device_waits,
+                                     _jax_scan_fn, _pack, _pass_waits,
+                                     _u_elements)
+    npad = 8 * _LINK_CHUNK
+    _jax_scan_fn().clear_cache()     # what earlier tests built is not warm
+    for b in (1, 4):
+        _device_waits(np.zeros((b, npad)), np.full((b, npad), -np.inf), "jax")
+    built = []
+
+    def on_compile(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            built.append(event)
+
+    rng = np.random.default_rng(7)
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        for n in (m for m in _LINK_LENGTHS if m > npad // 2):
+            _pass_waits(*_queue_pass(rng, n), "jax")
+            rows = [_u_elements(*_queue_pass(rng, m))
+                    for m in (n, n // 2, 3, n - 1)]
+            _device_scan(_pack(rows, "jax"), n, "jax")
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert built == []
 
 
 def test_order_by_server_arrival_repairs_ties_to_original_order():

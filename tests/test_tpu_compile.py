@@ -44,10 +44,14 @@ def test_lindley_scan_compiles_for_v5e(one_chip, shape):
 
 
 def test_jax_f64_scan_compiles_for_v5e(one_chip):
-    from repro.core.sim_scan import _jax_scan_fn
+    from repro.core.sim_scan import _chunk_width, _jax_scan_fn
+    npad = 1 << 16
+    width = _chunk_width(npad)
     with jax.enable_x64(True):
-        x = jax.ShapeDtypeStruct((1, 1 << 16), jnp.float64,
-                                 sharding=one_chip)
-        compiled = _jax_scan_fn().lower(x, x).compile()
+        x = jax.ShapeDtypeStruct((1, width), jnp.float64, sharding=one_chip)
+        compiled = _jax_scan_fn().lower(*[x] * (npad // width)).compile()
         out = compiled.out_info
-    assert out.dtype == jnp.float64 and out.shape == (1, 1 << 16)
+    # the waits come back as chunks of one (1, 2^16) f64 row
+    assert all(o.dtype == jnp.float64 for o in out)
+    assert {o.shape[0] for o in out} == {1}
+    assert sum(o.shape[-1] for o in out) == 1 << 16
